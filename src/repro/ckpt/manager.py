@@ -156,8 +156,9 @@ class CheckpointManager:
                 ) -> Tuple[int, Any]:
         """Reassemble the checkpoint (elastically: any current world size).
 
-        If `like` is given, the restored flat leaves are re-packed into its
-        treedef (shapes/dtypes verified leaf-by-leaf)."""
+        If `like` is given (arrays or `ShapeDtypeStruct`s), the restored flat
+        leaves are re-packed into its treedef (shapes checked leaf-by-leaf,
+        dtypes cast to the leaf's)."""
         if step is None:
             step = self.latest_step()
             if step is None:
@@ -188,10 +189,10 @@ class CheckpointManager:
             if name not in by_name:
                 raise KeyError(f"checkpoint missing leaf {name}")
             arr = by_name[name]
-            want = np.asarray(leaf)
-            if tuple(arr.shape) != tuple(want.shape):
-                raise ValueError(f"{name}: ckpt shape {arr.shape} != {want.shape}")
-            leaves.append(arr.astype(want.dtype))
+            # read shape/dtype only: `like` may be device arrays or shapes
+            if tuple(arr.shape) != tuple(leaf.shape):
+                raise ValueError(f"{name}: ckpt shape {arr.shape} != {leaf.shape}")
+            leaves.append(arr.astype(leaf.dtype, copy=False))
         return step, jax.tree_util.tree_unflatten(treedef, leaves)
 
     # ------------------------------------------------------------------

@@ -54,6 +54,8 @@ class DataPipeline:
             maxsize=prefetch)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        self._start_step = sampler.step
+        self.consumed = 0  # batches handed to the consumer
 
     # --- sample read with hedging ---------------------------------------
     def _read_sample(self, idx: int) -> np.ndarray:
@@ -114,6 +116,7 @@ class DataPipeline:
 
     def start(self) -> "DataPipeline":
         self.dataset.warm_dirs()  # metadata RPCs happen HERE, once
+        self._start_step = self.sampler.step
         self._thread = threading.Thread(target=self._producer, daemon=True)
         self._thread.start()
         return self
@@ -127,7 +130,16 @@ class DataPipeline:
                 return
             if isinstance(item, Exception):
                 raise item
+            self.consumed += 1
             yield item
+
+    def state_dict(self) -> dict:
+        """Sampler state just after the batches the consumer has taken.
+
+        The producer runs up to `prefetch + 1` batches ahead, so the
+        sampler's own cursor is past where a resumed run must start."""
+        return {**self.sampler.state_dict(),
+                "step": self._start_step + self.consumed}
 
     def stop(self) -> None:
         self._stop.set()

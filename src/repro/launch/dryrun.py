@@ -1,5 +1,5 @@
 import os
-os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_EXTRA_XLA", "") +
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
@@ -168,7 +168,7 @@ def run_cell(arch: str, shape: InputShape, *, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
     act = sh.activation_specs_for(mesh, shape, cfg)
-    with mesh, activation_specs(act):
+    with jax.set_mesh(mesh), activation_specs(act):
         fn, args = build_cell(cfg, shape, mesh)
         lowered = fn.lower(*args)
         t_lower = time.time() - t0

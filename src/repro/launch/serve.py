@@ -1,10 +1,11 @@
-"""Batched serving driver: prefill + decode loop with continuous batching.
+"""Batched serving driver: prefill + greedy decode of one static batch.
 
 Loads (or initializes) a model, serves a batch of token prompts with a KV /
-SSM-state cache, and streams greedy tokens.  The same `serve_step` the
-multi-pod dry-run lowers is used here on the host mesh, so what is served is
-exactly what was dry-run.
+SSM-state cache, and returns greedy tokens.  The same `serve_step` the
+multi-pod dry-run lowers runs here on the default device, so what is served
+is exactly what was dry-run.
 
+Reduced config by default; `--full` for the published widths:
     PYTHONPATH=src python -m repro.launch.serve --arch stablelm-3b --tokens 32
 """
 from __future__ import annotations
@@ -20,6 +21,7 @@ import numpy as np
 from ..configs import get_config
 from ..models import init_cache, init_model
 from ..runtime.steps import prefill_step, serve_step
+from .compile_cache import use_compile_cache
 
 
 class Server:
@@ -59,6 +61,7 @@ class Server:
             batch["embeds"] = jnp.asarray(emb, jnp.bfloat16)
         t0 = time.time()
         logits, cache = self._prefill(self.params, cache, batch)
+        logits.block_until_ready()
         prefill_s = time.time() - t0
 
         outs: List[np.ndarray] = []
@@ -73,6 +76,7 @@ class Server:
             logits, cache = self._decode(self.params, cache, step_batch,
                                          jnp.int32(s0 + i))
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        tok.block_until_ready()
         decode_s = time.time() - t0
         return {"tokens": np.stack(outs, 1),
                 "prefill_s": prefill_s,
@@ -85,8 +89,11 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--tokens", type=int, default=32)
+    ap.add_argument("--full", action="store_true",
+                    help="published widths and depth instead of reduced")
     args = ap.parse_args()
-    srv = Server(args.arch)
+    use_compile_cache()
+    srv = Server(args.arch, reduced=not args.full)
     rng = np.random.default_rng(0)
     prompts = rng.integers(1, srv.cfg.vocab_size,
                            size=(args.batch, args.prompt_len)).astype(np.int32)

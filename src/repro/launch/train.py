@@ -2,13 +2,14 @@
 
 Wires every substrate together: BuffetFS-served data pipeline (prefetch +
 hedged reads), checkpoint/restart over BuffetFS (async, atomic), AdamW, and
-the jitted train step on a device mesh.  Designed so a SIGKILL at any step
-loses at most `ckpt_every` steps of work and a restart resumes exactly
-(sampler state rides in the checkpoint manifest).
+the jitted train step on the default device (no mesh is built).  Designed
+so a SIGKILL at any step loses at most `ckpt_every` steps of work and a
+restart resumes exactly: the count of batches the step has consumed rides
+in the checkpoint manifest.
 
-CLI (CPU-scale example; the same driver works under a real TPU mesh):
+CLI (reduced config by default; `--full` for the published widths):
     PYTHONPATH=src python -m repro.launch.train --arch stablelm-3b \
-        --steps 100 --reduced --batch 8 --seq 128
+        --steps 100 --batch 8 --seq 128
 """
 from __future__ import annotations
 
@@ -29,7 +30,7 @@ from ..core import BAgent, BLib, BuffetCluster
 from ..data import BuffetDataset, DataPipeline, ShardedSampler
 from ..optim import AdamWConfig
 from ..runtime.steps import make_train_state, make_train_step_fn
-from .mesh import make_host_mesh
+from .compile_cache import use_compile_cache
 
 
 @dataclass
@@ -98,31 +99,34 @@ class Trainer:
     def init_or_restore(self) -> None:
         self.state = make_train_state(self.cfg, self.opt_cfg,
                                       jax.random.PRNGKey(0))
-        if self.tc.resume:
-            try:
-                step, restored = self.ckpt.restore(like=self.state)
-                self.state = restored
-                man = self.ckpt.manifest(step)
-                self.sampler.load_state_dict(man.extra["sampler"])
-                self.start_step = int(man.extra["train_step"])
-                print(f"[trainer] resumed from step {self.start_step}")
-            except (FileNotFoundError, KeyError):
-                print("[trainer] fresh start")
+        if not self.tc.resume:
+            return
+        try:
+            step, restored = self.ckpt.restore(like=self.state)
+        except FileNotFoundError:  # no committed checkpoint
+            print("[trainer] fresh start")
+            return
+        self.state = jax.device_put(restored)
+        man = self.ckpt.manifest(step)
+        self.sampler.load_state_dict(man.extra["sampler"])
+        self.start_step = int(man.extra["train_step"])
+        print(f"[trainer] resumed from step {self.start_step}")
 
     # ------------------------------------------------------------------
-    def run(self) -> Dict[str, float]:
+    def run(self) -> Dict[str, Any]:
         if self.state is None:
             self.init_or_restore()
         tc = self.tc
         it = iter(self.pipeline)
         last_loss = float("nan")
+        losses: Dict[int, float] = {}
         t0 = time.time()
         for step in range(self.start_step, tc.steps):
             batch = next(it)
             jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
             self.state, metrics = self.step_fn(self.state, jbatch)
             if (step + 1) % tc.log_every == 0 or step == tc.steps - 1:
-                last_loss = float(metrics["loss"])
+                last_loss = losses[step + 1] = float(metrics["loss"])
                 dt = time.time() - t0
                 print(f"[trainer] step {step+1}/{tc.steps} "
                       f"loss={last_loss:.4f} lr={float(metrics['lr']):.2e} "
@@ -132,13 +136,13 @@ class Trainer:
                 # async save: training continues while BuffetFS persists
                 self.ckpt.save(step + 1, self.state, block=False, extra={
                     "train_step": step + 1,
-                    "sampler": self.sampler.state_dict(),
+                    "sampler": self.pipeline.state_dict(),
                     "arch": self.cfg.name,
                 })
         self.ckpt.wait()
         self.pipeline.stop()
         rpc = self.agent.stats.snapshot()
-        return {"final_loss": last_loss, "steps": tc.steps,
+        return {"final_loss": last_loss, "losses": losses, "steps": tc.steps,
                 "critical_rpcs": rpc["critical_path"],
                 "async_rpcs": rpc["async_offpath"]}
 
@@ -160,6 +164,7 @@ def main() -> None:
     ap.add_argument("--data-dir", default=None)
     ap.add_argument("--run", default="run0")
     args = ap.parse_args()
+    use_compile_cache()
     tc = TrainerConfig(arch=args.arch, steps=args.steps,
                        global_batch=args.batch, seq_len=args.seq, lr=args.lr,
                        reduced=args.reduced, data_dir=args.data_dir,

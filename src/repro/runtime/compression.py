@@ -16,7 +16,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PyTree = Any
@@ -56,7 +55,7 @@ def compressed_psum_tree(grads: PyTree, mesh: Mesh, axis: str = "pod") -> PyTree
     def reduce_leaf(g):
         spec = P()  # leaf fully replicated w.r.t. the pod axis
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
                            out_specs=spec)
         def inner(gl):
             q, scale = _quantize(gl)

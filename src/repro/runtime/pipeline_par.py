@@ -17,7 +17,6 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PyTree = Any
@@ -41,8 +40,8 @@ def pipeline_forward(layer_fn: Callable[[PyTree, jnp.ndarray], jnp.ndarray],
     pspec = P(axis)   # stage dim sharded: each device holds its stage slice
     xspec = P()       # activations replicated per stage group
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(pspec, xspec),
-                       out_specs=xspec, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(pspec, xspec),
+                       out_specs=xspec, check_vma=False)
     def run(params_local, xg):
         stage = jax.lax.axis_index(axis)
         params_mine = jax.tree_util.tree_map(lambda a: a[0], params_local)

@@ -69,7 +69,7 @@ def test_collectives_counted_with_mesh():
     def f(a):
         return jax.lax.with_sharding_constraint(a.sum(0), P())
 
-    with mesh:
+    with jax.set_mesh(mesh):
         hlo = jax.jit(
             f, in_shardings=NamedSharding(mesh, P("data", None)),
         ).lower(x).compile().as_text()

@@ -79,6 +79,24 @@ def test_pipeline_produces_batches(cluster, lib):
     pipe.stop()
 
 
+def test_pipeline_state_counts_consumed_batches(cluster, lib):
+    """The producer runs ahead of the consumer; the resumable state is the
+    count of batches the consumer took, not the sampler's cursor."""
+    ds, _ = _mk_corpus(lib)
+    sampler = ShardedSampler(n_samples=len(ds), global_batch=4, dp_rank=0,
+                             dp_size=1)
+    pipe = DataPipeline(ds, sampler, seq_len=16, prefetch=2)
+    it = iter(pipe)
+    for _ in range(3):
+        next(it)
+    deadline = time.time() + 10
+    while sampler.step <= 3 and time.time() < deadline:
+        time.sleep(0.01)
+    assert sampler.step > 3  # prefetch ran ahead
+    assert pipe.state_dict() == {"step": 3, "seed": 0}
+    pipe.stop()
+
+
 def test_pipeline_epoch_rpc_efficiency(cluster):
     """After warm-up, one epoch over N samples costs ~N critical RPCs —
     the BuffetFS property, measured end-to-end through the pipeline."""
@@ -190,6 +208,24 @@ def test_ckpt_corruption_detected(lib):
     lib.write_file(victim, b"corrupted bytes")
     with pytest.raises(IOError):
         mgr.restore(3, like=_tree())
+
+
+def test_ckpt_restore_like_shapes(lib):
+    """`like` may be shapes only: restore reads each leaf's shape and dtype
+    and never needs its values."""
+    import jax
+    mgr = CheckpointManager(lib, "runG", parts=2)
+    tree = _tree()
+    mgr.save(2, tree)
+    like = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, np.float16), tree)
+    step, restored = mgr.restore(like=like)
+    assert step == 2
+    assert restored["w"].dtype == np.float16
+    np.testing.assert_array_equal(restored["w"], tree["w"].astype(np.float16))
+    bad = dict(like, b=jax.ShapeDtypeStruct((9,), np.float32))
+    with pytest.raises(ValueError, match="ckpt shape"):
+        mgr.restore(like=bad)
 
 
 def test_hedged_read_survives_dead_server(cluster):

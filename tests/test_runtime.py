@@ -1,5 +1,5 @@
 """Runtime tests: sharding rules, optimizer, compression, pipeline-parallel,
-elastic restore, end-to-end trainer convergence + crash/restart."""
+end-to-end trainer convergence + crash/restart."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +7,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import TRAIN_4K, get_config
+from repro.launch.mesh import make_host_mesh
 from repro.optim import AdamWConfig, adamw_update, init_opt_state, lr_at
 from repro.runtime import sharding as sh
 from repro.runtime.steps import model_axes, abstract_params
@@ -15,7 +16,7 @@ from repro.runtime.steps import model_axes, abstract_params
 def _mesh2x2():
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 devices (run under XLA_FLAGS host device count)")
-    return jax.make_mesh((2, 2), ("data", "model"))
+    return make_host_mesh(2, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -101,7 +102,7 @@ def test_compressed_psum_tree_accuracy():
     from repro.runtime.compression import compressed_psum_tree
     mesh = jax.make_mesh((2, 2), ("pod", "data"))
     g = {"w": jnp.linspace(-1.0, 1.0, 512).reshape(2, 256)}
-    with mesh:
+    with jax.set_mesh(mesh):
         out = compressed_psum_tree(g, mesh, axis="pod")
     # replicated input: mean over pod = identity (up to int8 quantization)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
@@ -128,7 +129,7 @@ def test_pipeline_forward_matches_sequential():
     for i in range(s_stages):
         ref = layer_fn(ws[i], ref)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         out = pipeline_forward(layer_fn, ws, x, mesh=mesh, axis="pod",
                                n_microbatches=4)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
@@ -161,22 +162,59 @@ def test_trainer_loss_decreases(tmp_path):
 
 
 def test_trainer_crash_restart_resumes(tmp_path):
+    """Kill before the last checkpoint commits, resume from the one before,
+    and finish on exactly the loss of the uninterrupted run."""
     from repro.launch.train import Trainer, TrainerConfig
-    tc = TrainerConfig(arch="stablelm-3b", steps=10, global_batch=4,
-                       seq_len=32, ckpt_every=5, log_every=100,
+    tc = TrainerConfig(arch="mamba2-130m", steps=6, global_batch=4,
+                       seq_len=64, ckpt_every=3, log_every=1,
                        data_dir=str(tmp_path), n_servers=2, run_name="cr")
     tr = Trainer(tc)
-    tr.run()          # writes checkpoints at steps 5 and 10
+    full = tr.run()   # commits checkpoints at steps 3 and 6
+    # the job dies before step 6 commits: without its MANIFEST the step is
+    # invisible (atomic commit)
+    tr.lib.unlink(f"{tr.ckpt._step_dir(6)}/MANIFEST")
     tr.shutdown()
 
-    # "crash": new trainer over the same BuffetFS dir resumes from step 10
-    tc2 = TrainerConfig(arch="stablelm-3b", steps=12, global_batch=4,
-                        seq_len=32, ckpt_every=5, log_every=100,
-                        data_dir=str(tmp_path), n_servers=2, run_name="cr")
-    tr2 = Trainer(tc2)
+    tr2 = Trainer(tc)
     tr2.init_or_restore()
-    assert tr2.start_step == 10
-    assert tr2.sampler.step == tr2.sampler.state_dict()["step"]
-    out = tr2.run()   # only 2 more steps
-    assert np.isfinite(out["final_loss"])
+    assert tr2.start_step == 3
+    assert tr2.sampler.step == 3  # batches consumed, not the prefetch cursor
+    resumed = tr2.run()           # steps 4-6
     tr2.shutdown()
+    assert sorted(resumed["losses"]) == [4, 5, 6]
+    assert all(np.isfinite(v) for v in full["losses"].values())
+    assert resumed["final_loss"] == pytest.approx(full["final_loss"], rel=1e-6)
+
+
+def test_trainer_restore_missing_leaf_fails_loudly(tmp_path):
+    from repro.launch.train import Trainer, TrainerConfig
+    tc = TrainerConfig(arch="mamba2-130m", steps=2, global_batch=2,
+                       seq_len=32, data_dir=str(tmp_path), n_servers=2)
+    tr = Trainer(tc)
+    try:
+        tr.init_or_restore()
+        partial = {"params": tr.state["params"]}  # optimizer state missing
+        tr.ckpt.save(1, partial, extra={"train_step": 1,
+                                        "sampler": tr.pipeline.state_dict()})
+        with pytest.raises(KeyError, match="checkpoint missing leaf"):
+            tr.init_or_restore()
+    finally:
+        tr.shutdown()
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the launchers pin
+    the cache to <repo>/.jax_cache, never a per-run path."""
+    from pathlib import Path
+    from repro.launch.compile_cache import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo_cache = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert use_compile_cache() == repo_cache
+        assert jax.config.jax_compilation_cache_dir == repo_cache
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

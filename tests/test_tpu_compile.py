@@ -1,0 +1,103 @@
+"""Compile the main-path programs for a TPU v5e chip that is described, not
+attached: the trainer's step and the server's prefill and decode at full
+width, and the Pallas RMSNorm kernel at the model widths.  Nothing runs;
+the TPU compiler refuses what the chip could not run (tiling, VMEM, HBM).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every pytest
+worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.configs.base import InputShape
+from repro.kernels.rmsnorm.ops import rmsnorm_op
+from repro.models import init_cache
+from repro.optim import AdamWConfig
+from repro.runtime.steps import (abstract_batch, abstract_state,
+                                 make_train_step_fn, prefill_step, serve_step)
+
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back without one: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_mamba2_train_step_fits_one_chip(one_chip):
+    """The step chip_smoke.py trains: mamba2-130m at full width, 8 x 2048."""
+    cfg = get_config("mamba2-130m")
+    opt_cfg = AdamWConfig(total_steps=6, warmup_steps=1)
+    shape = InputShape("smoke_train", 2048, 8, "train")
+    state = _on(abstract_state(cfg, opt_cfg), one_chip)
+    batch = _on(abstract_batch(cfg, shape), one_chip)
+    step = jax.jit(make_train_step_fn(cfg, opt_cfg), donate_argnums=(0,))
+    compiled = step.lower(state, batch).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_stablelm_serve_steps_fit_one_chip(one_chip, phase):
+    """The server chip_smoke.py runs: stablelm-3b full, batch 4, max_len 512,
+    128-token prompts."""
+    cfg = get_config("stablelm-3b")
+    b, prompt, max_len = 4, 128, 512
+    params = _on(abstract_state(cfg, AdamWConfig())["params"], one_chip)
+    cache = _on(jax.eval_shape(lambda: init_cache(cfg, b, max_len)), one_chip)
+    if phase == "prefill":
+        fn = jax.jit(lambda p, c, x: prefill_step(p, c, x, cfg),
+                     donate_argnums=(1,))
+        tokens = jax.ShapeDtypeStruct((b, prompt), jnp.int32, sharding=one_chip)
+        compiled = fn.lower(params, cache, {"tokens": tokens}).compile()
+    else:
+        fn = jax.jit(lambda p, c, x, pos: serve_step(p, c, x, pos, cfg),
+                     donate_argnums=(1,))
+        tokens = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
+        pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        compiled = fn.lower(params, cache, {"tokens": tokens}, pos).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("rows,d", [(4 * 128, 2560), (8 * 2048, 768)])
+def test_rmsnorm_kernel_compiles(one_chip, rows, d):
+    x = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((d,), jnp.bfloat16, sharding=one_chip)
+    compiled = rmsnorm_op.lower(x, scale).compile()
+    assert "tpu_custom_call" in compiled.as_text()
