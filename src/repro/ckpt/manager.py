@@ -32,6 +32,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import ml_dtypes  # registers bfloat16/f8 numpy dtypes (np.dtype("bfloat16"))
 import numpy as np
 
+from .. import obs
 from ..core.blib import BLib
 
 try:  # tree utilities without requiring jax at import time for pure-data users
@@ -86,50 +87,64 @@ class CheckpointManager:
         np.save(buf, arr, allow_pickle=False)
         return buf.getvalue()
 
-    def _write_tree(self, step: int, tree: Any, extra: Dict[str, Any]) -> None:
-        sdir = self._step_dir(step)
-        self.lib.makedirs(sdir)
-        flat, _ = _tree_flatten(tree)
-        leaves_meta: List[Dict[str, Any]] = []
-        for kp, leaf in flat:
-            arr = np.asarray(leaf)
-            name = _leaf_name(kp)
-            nparts = self.parts if (arr.ndim > 0 and arr.shape[0] >= self.parts) else 1
-            chunks = np.array_split(arr, nparts, axis=0) if nparts > 1 else [arr]
-            files = []
-            for pi, chunk in enumerate(chunks):
-                pdir = f"{sdir}/part_{pi:03d}"
-                self.lib.makedirs(pdir)
-                path = f"{pdir}/{name}.npy"
-                blob = self._np_bytes(chunk)
-                self.lib.write_file(path, blob)
-                files.append({"path": path, "crc": zlib.crc32(blob)})
-            leaves_meta.append({"name": name, "shape": list(arr.shape),
-                                "dtype": str(arr.dtype), "files": files})
-        man = Manifest(step=step, parts=self.parts, leaves=leaves_meta, extra=extra)
-        self.lib.write_file(f"{sdir}/MANIFEST", man.to_bytes())
-        self._gc()
+    def _write_tree(self, step: int, tree: Any, extra: Dict[str, Any],
+                    cause: Optional[int] = None) -> None:
+        with obs.span("ckpt.write", cause=cause, step=step):
+            sdir = self._step_dir(step)
+            self.lib.makedirs(sdir)
+            flat, _ = _tree_flatten(tree)
+            leaves_meta: List[Dict[str, Any]] = []
+            for kp, leaf in flat:
+                arr = np.asarray(leaf)
+                name = _leaf_name(kp)
+                nparts = (self.parts if arr.ndim > 0 and arr.shape[0] >= self.parts
+                          else 1)
+                chunks = (np.array_split(arr, nparts, axis=0) if nparts > 1
+                          else [arr])
+                files = []
+                for pi, chunk in enumerate(chunks):
+                    pdir = f"{sdir}/part_{pi:03d}"
+                    self.lib.makedirs(pdir)
+                    path = f"{pdir}/{name}.npy"
+                    with obs.span("ckpt.serialize"):
+                        blob = self._np_bytes(chunk)
+                    self.lib.write_file(path, blob)
+                    with obs.span("ckpt.crc"):
+                        crc = zlib.crc32(blob)
+                    files.append({"path": path, "crc": crc})
+                leaves_meta.append({"name": name, "shape": list(arr.shape),
+                                    "dtype": str(arr.dtype), "files": files})
+            man = Manifest(step=step, parts=self.parts, leaves=leaves_meta,
+                           extra=extra)
+            with obs.span("ckpt.commit"):
+                self.lib.write_file(f"{sdir}/MANIFEST", man.to_bytes())
+            with obs.span("ckpt.gc"):
+                self._gc()
 
     # ------------------------------------------------------------------
     def save(self, step: int, tree: Any, *, extra: Optional[Dict[str, Any]] = None,
              block: bool = True) -> None:
         extra = extra or {}
-        # snapshot to host memory NOW (cheap on CPU; device->host on TPU),
-        # so async writing races with nothing
-        snap = jax.tree_util.tree_map(lambda x: np.array(x), tree)
-        if block:
-            with self._save_lock:
-                self._write_tree(step, snap, extra)
-            return
-        self.wait()
-        self._inflight = threading.Thread(
-            target=lambda: self._write_tree(step, snap, extra), daemon=True)
-        self._inflight.start()
+        with obs.span("ckpt.save", step=step) as sid:
+            # snapshot to host memory NOW (cheap on CPU; device->host on
+            # TPU), so async writing races with nothing
+            with obs.span("ckpt.snapshot"):
+                snap = jax.tree_util.tree_map(lambda x: np.array(x), tree)
+            if block:
+                with self._save_lock:
+                    self._write_tree(step, snap, extra)
+                return
+            self.wait()
+            self._inflight = threading.Thread(
+                target=lambda: self._write_tree(step, snap, extra, cause=sid),
+                name="ckpt-writer", daemon=True)
+            self._inflight.start()
 
     def wait(self) -> None:
-        if self._inflight is not None:
-            self._inflight.join()
-            self._inflight = None
+        with obs.span("ckpt.wait"):
+            if self._inflight is not None:
+                self._inflight.join()
+                self._inflight = None
 
     # ------------------------------------------------------------------
     def steps(self) -> List[int]:
@@ -163,37 +178,47 @@ class CheckpointManager:
             step = self.latest_step()
             if step is None:
                 raise FileNotFoundError("no committed checkpoint")
+        with obs.span("ckpt.restore", step=step):
+            return step, self._read_tree(step, like)
+
+    def _read_tree(self, step: int, like: Any) -> Any:
         man = self.manifest(step)
         by_name: Dict[str, np.ndarray] = {}
         for lm in man.leaves:
             parts = []
             for f in lm["files"]:
                 blob = self.lib.read_file(f["path"])
-                if zlib.crc32(blob) != f["crc"]:
-                    raise IOError(f"checksum mismatch in {f['path']}")
-                part = np.load(io.BytesIO(blob), allow_pickle=False)
-                if part.dtype.kind == "V":
-                    # custom dtypes (bfloat16, f8) round-trip through .npy as
-                    # raw void records; re-view with the manifest dtype
-                    part = part.view(np.dtype(lm["dtype"]))
+                with obs.span("ckpt.verify"):
+                    if zlib.crc32(blob) != f["crc"]:
+                        raise IOError(f"checksum mismatch in {f['path']}")
+                with obs.span("ckpt.decode"):
+                    part = np.load(io.BytesIO(blob), allow_pickle=False)
+                    if part.dtype.kind == "V":
+                        # custom dtypes (bfloat16, f8) round-trip through
+                        # .npy as raw void records; re-view with the
+                        # manifest dtype
+                        part = part.view(np.dtype(lm["dtype"]))
                 parts.append(part)
-            arr = np.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
-            arr = arr.reshape(lm["shape"]).astype(np.dtype(lm["dtype"]))
+            with obs.span("ckpt.assemble"):
+                arr = np.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+                arr = arr.reshape(lm["shape"]).astype(np.dtype(lm["dtype"]))
             by_name[lm["name"]] = arr
         if like is None:
-            return step, by_name
+            return by_name
         flat, treedef = _tree_flatten(like)
         leaves = []
-        for kp, leaf in flat:
-            name = _leaf_name(kp)
-            if name not in by_name:
-                raise KeyError(f"checkpoint missing leaf {name}")
-            arr = by_name[name]
-            # read shape/dtype only: `like` may be device arrays or shapes
-            if tuple(arr.shape) != tuple(leaf.shape):
-                raise ValueError(f"{name}: ckpt shape {arr.shape} != {leaf.shape}")
-            leaves.append(arr.astype(leaf.dtype, copy=False))
-        return step, jax.tree_util.tree_unflatten(treedef, leaves)
+        with obs.span("ckpt.assemble"):
+            for kp, leaf in flat:
+                name = _leaf_name(kp)
+                if name not in by_name:
+                    raise KeyError(f"checkpoint missing leaf {name}")
+                arr = by_name[name]
+                # read shape/dtype only: `like` may be device arrays or shapes
+                if tuple(arr.shape) != tuple(leaf.shape):
+                    raise ValueError(
+                        f"{name}: ckpt shape {arr.shape} != {leaf.shape}")
+                leaves.append(arr.astype(leaf.dtype, copy=False))
+        return jax.tree_util.tree_unflatten(treedef, leaves)
 
     # ------------------------------------------------------------------
     def _gc(self) -> None:

@@ -12,6 +12,7 @@ from __future__ import annotations
 import errno
 from typing import Iterator, List, Optional
 
+from .. import obs
 from .bagent import BAgent
 from .perms import O_CREAT, O_RDONLY, O_RDWR, O_TRUNC, O_WRONLY, err
 
@@ -79,7 +80,7 @@ class BLib:
         cache (``BAgent(read_cache=True)``) a warm re-read costs ZERO
         critical-path RPCs — open() checks permissions locally, the data
         comes from cached blocks, and close() never touched the server."""
-        with self.open(path, "rb") as f:
+        with obs.span("fs.read_file"), self.open(path, "rb") as f:
             return f.read()
 
     def cache_stats(self) -> Optional[dict]:
@@ -89,12 +90,13 @@ class BLib:
     def read_files(self, paths: List[str]) -> List[bytes]:
         """Bulk whole-file read over the agent's batched open/read path:
         O(depth + hosts) RPCs for the lot instead of one per file."""
-        fds = self.agent.open_many(list(paths), O_RDONLY)
-        try:
-            return self.agent.read_many(fds)
-        finally:
-            for fd in fds:
-                self.agent.close(fd)
+        with obs.span("fs.read_files"):
+            fds = self.agent.open_many(list(paths), O_RDONLY)
+            try:
+                return self.agent.read_many(fds)
+            finally:
+                for fd in fds:
+                    self.agent.close(fd)
 
     def warm_tree(self, path: str = "/") -> int:
         """Prefetch the whole namespace subtree under `path` (bulk
@@ -102,7 +104,7 @@ class BLib:
         return self.agent.warm_tree(path)
 
     def write_file(self, path: str, data: bytes, perm: int = 0o644) -> int:
-        with self.open(path, "wb", perm) as f:
+        with obs.span("fs.write_file"), self.open(path, "wb", perm) as f:
             return f.write(data)
 
     def write_files(self, paths: List[str], blobs: List[bytes],
@@ -111,16 +113,17 @@ class BLib:
         CREATE BATCHes), then per-file writes — which a write-behind agent
         buffers and flushes off the critical path in coalesced per-host
         batches.  Returns the total bytes written."""
-        fds = self.agent.open_many(list(paths), O_WRONLY | O_CREAT | O_TRUNC,
-                                   perm)
-        total = 0
-        try:
-            for fd, blob in zip(fds, blobs):
-                total += self.agent.write(fd, blob)
-        finally:
-            for fd in fds:
-                self.agent.close(fd)
-        return total
+        with obs.span("fs.write_files"):
+            fds = self.agent.open_many(list(paths),
+                                       O_WRONLY | O_CREAT | O_TRUNC, perm)
+            total = 0
+            try:
+                for fd, blob in zip(fds, blobs):
+                    total += self.agent.write(fd, blob)
+            finally:
+                for fd in fds:
+                    self.agent.close(fd)
+            return total
 
     # --- namespace ---------------------------------------------------------
     def mkdir(self, path: str, mode: int = 0o755) -> None:
@@ -129,23 +132,26 @@ class BLib:
     def makedirs(self, path: str, mode: int = 0o755) -> None:
         parts = [p for p in path.split("/") if p]
         cur = ""
-        for p in parts:
-            cur += "/" + p
-            try:
-                self.agent.mkdir(cur, mode)
-            except OSError as e:
-                if e.errno != errno.EEXIST:
-                    raise
+        with obs.span("fs.makedirs"):
+            for p in parts:
+                cur += "/" + p
+                try:
+                    self.agent.mkdir(cur, mode)
+                except OSError as e:
+                    if e.errno != errno.EEXIST:
+                        raise
 
     def listdir(self, path: str) -> List[str]:
-        return self.agent.readdir(path)
+        with obs.span("fs.listdir"):
+            return self.agent.readdir(path)
 
     def exists(self, path: str) -> bool:
-        try:
-            self.agent.stat_cached(path)
-            return True
-        except OSError:
-            return False
+        with obs.span("fs.exists"):
+            try:
+                self.agent.stat_cached(path)
+                return True
+            except OSError:
+                return False
 
     def layout(self, path: str) -> Optional[dict]:
         """The file's stripe layout ({"ss": stripe_size, "hosts": [...]})
@@ -205,7 +211,8 @@ class BLib:
         return self.agent.stat(path)
 
     def unlink(self, path: str) -> None:
-        self.agent.unlink(path)
+        with obs.span("fs.unlink"):
+            self.agent.unlink(path)
 
     def chmod(self, path: str, mode: int) -> None:
         self.agent.chmod(path, mode)

@@ -192,6 +192,7 @@ class InProcTransport(Transport):
 
     def request(self, addr: Addr, msg: Message, *, critical: bool = True,
                 stats: Optional[RpcStats] = None) -> Message:
+        t0 = time.perf_counter_ns()
         with self._lock:
             handler = self._handlers.get(addr)
             svc_lock = self._svc_locks.get(addr)
@@ -247,7 +248,8 @@ class InProcTransport(Transport):
             # frame build), so encode_ns/decode_ns stay 0 and benchmarks on
             # this transport measure protocol cost, not codec cost
             stats.record(msg.type, req_bytes, resp_bytes, critical,
-                         subops=n_sub, addr=addr)
+                         subops=n_sub, addr=addr,
+                         wait_ns=time.perf_counter_ns() - t0)
         return resp
 
     def request_many(self, addr: Addr, msgs: List[Message], *,
@@ -603,7 +605,7 @@ class TCPTransport(Transport):
             return None
         return conn, rid, waiter
 
-    def _await(self, addr: Addr, msg: Message, handle, *,
+    def _await(self, addr: Addr, msg: Message, handle, *, t0_ns: int,
                critical: bool, stats: Optional[RpcStats]) -> Message:
         if handle is None:
             return error(107, f"server {addr!r} unreachable")  # ENOTCONN
@@ -626,12 +628,14 @@ class TCPTransport(Transport):
             stats.record(msg.type, msg.nbytes, resp.nbytes, critical,
                          subops=n_sub, addr=addr,
                          encode_ns=msg._encode_ns,
-                         decode_ns=resp._decode_ns)
+                         decode_ns=resp._decode_ns,
+                         wait_ns=time.perf_counter_ns() - t0_ns)
         return resp
 
     def request(self, addr: Addr, msg: Message, *, critical: bool = True,
                 stats: Optional[RpcStats] = None) -> Message:
-        return self._await(addr, msg, self._submit(addr, msg),
+        t0 = time.perf_counter_ns()
+        return self._await(addr, msg, self._submit(addr, msg), t0_ns=t0,
                            critical=critical, stats=stats)
 
     def request_many(self, addr: Addr, msgs: List[Message], *,
@@ -639,6 +643,8 @@ class TCPTransport(Transport):
                      ) -> List[Message]:
         """Pipelined fan-out: send every frame before collecting any
         response, so N requests cost ~1 RTT + N service times."""
+        t0 = time.perf_counter_ns()
         waiters = [self._submit(addr, m) for m in msgs]
-        return [self._await(addr, m, w, critical=critical, stats=stats)
+        return [self._await(addr, m, w, t0_ns=t0, critical=critical,
+                            stats=stats)
                 for m, w in zip(msgs, waiters)]

@@ -568,16 +568,20 @@ class RpcStats:
         # from transfer cost
         self.encode_ns: Counter = Counter()
         self.decode_ns: Counter = Counter()
+        # per-verb time (ns) callers blocked on critical RPCs, from the
+        # transport's request() entry to its return
+        self.wait_ns: Counter = Counter()
 
     def record(self, msg_type: MsgType, sent: int, recv: int, critical: bool,
                subops: int = 1, addr: str = "", encode_ns: int = 0,
-               decode_ns: int = 0) -> None:
+               decode_ns: int = 0, wait_ns: int = 0) -> None:
         with self._lock:
             self.by_type[msg_type.name] += 1
             if addr:
                 self.by_host[addr] += 1
             if critical:
                 self.critical_path += 1
+                self.wait_ns[msg_type.name] += wait_ns
             else:
                 self.async_offpath += 1
             self.bytes_sent += sent
@@ -605,6 +609,7 @@ class RpcStats:
                 "subops": self.subops,
                 "encode_ns": dict(self.encode_ns),
                 "decode_ns": dict(self.decode_ns),
+                "wait_ns": dict(self.wait_ns),
             }
 
     def reset(self) -> None:
@@ -618,3 +623,4 @@ class RpcStats:
             self.subops = 0
             self.encode_ns.clear()
             self.decode_ns.clear()
+            self.wait_ns.clear()

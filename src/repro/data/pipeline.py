@@ -22,6 +22,7 @@ from typing import Dict, Iterator, Optional
 
 import numpy as np
 
+from .. import obs
 from .dataset import BuffetDataset
 from .sampler import ShardedSampler
 from .tokens import pack_batch
@@ -88,7 +89,8 @@ class DataPipeline:
 
     def _build_batch(self, indices) -> Dict[str, np.ndarray]:
         samples = list(self._pool.map(self._read_sample, indices))
-        tokens, mask = pack_batch(samples, self.seq_len + 1, self.pad_id)
+        with obs.span("data.pack"):
+            tokens, mask = pack_batch(samples, self.seq_len + 1, self.pad_id)
         self.stats.batches += 1
         self.stats.samples += len(samples)
         return {
@@ -102,7 +104,10 @@ class DataPipeline:
         it = iter(self.sampler)
         while not self._stop.is_set():
             try:
-                batch = self._build_batch(next(it))
+                indices = next(it)
+                # the sampler's cursor is the step of the batch just drawn
+                with obs.span("data.build_batch", step=self.sampler.step):
+                    batch = self._build_batch(indices)
             except Exception as e:  # surface to the consumer, don't die mute
                 batch = e
             while not self._stop.is_set():
@@ -117,7 +122,8 @@ class DataPipeline:
     def start(self) -> "DataPipeline":
         self.dataset.warm_dirs()  # metadata RPCs happen HERE, once
         self._start_step = self.sampler.step
-        self._thread = threading.Thread(target=self._producer, daemon=True)
+        self._thread = threading.Thread(target=self._producer,
+                                        name="data-producer", daemon=True)
         self._thread.start()
         return self
 

@@ -7,7 +7,8 @@ so a SIGKILL at any step loses at most `ckpt_every` steps of work and a
 restart resumes exactly: the count of batches the step has consumed rides
 in the checkpoint manifest.
 
-CLI (reduced config by default; `--full` for the published widths):
+CLI (reduced config by default; `--full` for the published widths;
+`--trace-dir DIR` records the program's spans and a profiler trace):
     PYTHONPATH=src python -m repro.launch.train --arch stablelm-3b \
         --steps 100 --batch 8 --seq 128
 """
@@ -16,7 +17,6 @@ from __future__ import annotations
 import argparse
 import os
 import tempfile
-import time
 from dataclasses import dataclass, replace
 from typing import Any, Dict, Optional
 
@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..ckpt import CheckpointManager
 from ..configs import get_config
 from ..core import BAgent, BLib, BuffetCluster
@@ -31,6 +32,7 @@ from ..data import BuffetDataset, DataPipeline, ShardedSampler
 from ..optim import AdamWConfig
 from ..runtime.steps import make_train_state, make_train_step_fn
 from .compile_cache import use_compile_cache
+from .trace_dir import traced
 
 
 @dataclass
@@ -97,8 +99,9 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def init_or_restore(self) -> None:
-        self.state = make_train_state(self.cfg, self.opt_cfg,
-                                      jax.random.PRNGKey(0))
+        with obs.span("train.fresh_state"):
+            self.state = make_train_state(self.cfg, self.opt_cfg,
+                                          jax.random.PRNGKey(0))
         if not self.tc.resume:
             return
         try:
@@ -106,7 +109,8 @@ class Trainer:
         except FileNotFoundError:  # no committed checkpoint
             print("[trainer] fresh start")
             return
-        self.state = jax.device_put(restored)
+        with obs.span("train.restore_put", step=step):
+            self.state = jax.device_put(restored)
         man = self.ckpt.manifest(step)
         self.sampler.load_state_dict(man.extra["sampler"])
         self.start_step = int(man.extra["train_step"])
@@ -120,18 +124,23 @@ class Trainer:
         it = iter(self.pipeline)
         last_loss = float("nan")
         losses: Dict[int, float] = {}
-        t0 = time.time()
+        # `step` counts the steps done before this one: it is the sampler
+        # step of the batch the step consumes
         for step in range(self.start_step, tc.steps):
-            batch = next(it)
-            jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
-            self.state, metrics = self.step_fn(self.state, jbatch)
+            with obs.span("train.batch_wait", step=step):
+                batch = next(it)
+            with obs.span("train.h2d", step=step):
+                jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
+            with obs.span("train.dispatch", step=step):
+                self.state, metrics = self.step_fn(self.state, jbatch)
             if (step + 1) % tc.log_every == 0 or step == tc.steps - 1:
-                last_loss = losses[step + 1] = float(metrics["loss"])
-                dt = time.time() - t0
-                print(f"[trainer] step {step+1}/{tc.steps} "
-                      f"loss={last_loss:.4f} lr={float(metrics['lr']):.2e} "
-                      f"gnorm={float(metrics['grad_norm']):.3f} "
-                      f"({dt:.1f}s, hedged={self.pipeline.stats.hedged})")
+                with obs.span("train.loss_sync", step=step):
+                    last_loss = losses[step + 1] = float(metrics["loss"])
+                    print(f"[trainer] step {step+1}/{tc.steps} "
+                          f"loss={last_loss:.4f} "
+                          f"lr={float(metrics['lr']):.2e} "
+                          f"gnorm={float(metrics['grad_norm']):.3f} "
+                          f"(hedged={self.pipeline.stats.hedged})")
             if (step + 1) % tc.ckpt_every == 0 or step == tc.steps - 1:
                 # async save: training continues while BuffetFS persists
                 self.ckpt.save(step + 1, self.state, block=False, extra={
@@ -163,14 +172,18 @@ def main() -> None:
     ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--data-dir", default=None)
     ap.add_argument("--run", default="run0")
+    ap.add_argument("--trace-dir", default=None,
+                    help="record program spans and a profiler trace of the "
+                         "job into this directory (spans.jsonl at exit)")
     args = ap.parse_args()
     use_compile_cache()
     tc = TrainerConfig(arch=args.arch, steps=args.steps,
                        global_batch=args.batch, seq_len=args.seq, lr=args.lr,
                        reduced=args.reduced, data_dir=args.data_dir,
                        run_name=args.run)
-    tr = Trainer(tc)
-    out = tr.run()
+    with traced(args.trace_dir):
+        tr = Trainer(tc)
+        out = tr.run()
     print(f"[trainer] done: {out}")
     tr.shutdown()
 
