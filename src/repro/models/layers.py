@@ -121,17 +121,21 @@ def init_attention(cfg: ModelConfig, key) -> Tuple[Params, PyTree]:
 
 
 def blocked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                             scale: float, *, q_offset=0,
+                             scale: float, *, cache=None, cache_len=None,
                              q_chunk: int = 512) -> jnp.ndarray:
     """Memory-bounded causal GQA attention.
 
-    q [B,Sq,H,dh]; k,v [B,T,Hkv,dh].  Streams over query chunks with
-    `lax.map` so peak memory is O(q_chunk * T) per head instead of
-    O(Sq * T): mandatory at 4k-32k sequence lengths on 16GB HBM.
-    `q_offset` is the absolute position of q[0] (decode/cache case).
+    q [B,Sq,H,dh]; k,v [B,Sq,Hkv,dh], the keys and values of the same
+    positions as q.  Streams over query chunks with `lax.map` so peak memory
+    is O(q_chunk * T) per head instead of O(Sq * T): mandatory at 4k-32k
+    sequence lengths on 16GB HBM.
+
+    With `cache` = (ck, cv) [B,T,Hkv,dh], q/k/v are a new chunk that follows
+    the cache's first `cache_len` entries: every query also attends to those,
+    read in place.  One softmax spans the cache block and the chunk block.
     """
     b, sq, h, dh = q.shape
-    t, hkv = k.shape[1], k.shape[2]
+    hkv = k.shape[2]
     rep = h // hkv
     q_chunk = min(q_chunk, sq)
     assert sq % q_chunk == 0
@@ -139,16 +143,26 @@ def blocked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     qg = q.reshape(b, nchunks, q_chunk, hkv, rep, dh).astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
-    t_idx = jnp.arange(t)
+    s_idx = jnp.arange(sq)
+    if cache is not None:
+        ckf, cvf = (c.astype(jnp.float32) for c in cache)
+        t = ckf.shape[1]
+        cache_mask = jnp.arange(t) < cache_len                  # [T]
 
     def one_chunk(ci):
         qc = qg[:, ci]                                          # [B,qc,G,R,dh]
-        sc = jnp.einsum("bsgrd,btgd->bgrst", qc, kf) * scale    # [B,G,R,qc,T]
-        q_idx = q_offset + ci * q_chunk + jnp.arange(q_chunk)
-        mask = t_idx[None, :] <= q_idx[:, None]                 # [qc, T]
+        sc = jnp.einsum("bsgrd,btgd->bgrst", qc, kf) * scale    # [B,G,R,qc,Sq]
+        q_idx = ci * q_chunk + jnp.arange(q_chunk)
+        mask = s_idx[None, :] <= q_idx[:, None]                 # [qc, Sq]
         sc = jnp.where(mask[None, None, None], sc, -1e30)
-        w = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("bgrst,btgd->bsgrd", w, vf)           # [B,qc,G,R,dh]
+        if cache is None:
+            w = jax.nn.softmax(sc, axis=-1)
+            return jnp.einsum("bgrst,btgd->bsgrd", w, vf)       # [B,qc,G,R,dh]
+        scc = jnp.einsum("bsgrd,btgd->bgrst", qc, ckf) * scale  # [B,G,R,qc,T]
+        scc = jnp.where(cache_mask, scc, -1e30)
+        w = jax.nn.softmax(jnp.concatenate([scc, sc], axis=-1), axis=-1)
+        return (jnp.einsum("bgrst,btgd->bsgrd", w[..., :t], cvf)
+                + jnp.einsum("bgrst,btgd->bsgrd", w[..., t:], vf))
 
     out = jax.lax.map(one_chunk, jnp.arange(nchunks))           # [NC,B,qc,G,R,dv]
     dv = v.shape[-1]  # may differ from q/k head dim (MLA)
@@ -162,10 +176,13 @@ def blocked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def attention_fwd(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                   positions: jnp.ndarray, *,
                   kv_cache: Optional[Dict[str, jnp.ndarray]] = None,
-                  cache_pos: Optional[jnp.ndarray] = None,
-                  q_chunk: int = 512):
+                  cache_pos=None, q_chunk: int = 512):
     """Causal self-attention.  If `kv_cache` is given, x is the new token
-    chunk (decode/incremental-prefill) appended at `cache_pos`."""
+    chunk (decode/incremental-prefill) that follows the cache's first
+    `cache_pos` entries.  The cache is only read; the second result is this
+    chunk's {k, v} [B,S,Hkv,dh] in the cache's dtype, for the caller to
+    write at `cache_pos` (see `write_cache`).  A `cache_pos` of Python 0
+    (prefill) reads nothing from the cache."""
     dh = cfg.head_dim
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
@@ -186,18 +203,27 @@ def attention_fwd(p: Params, x: jnp.ndarray, cfg: ModelConfig,
         v = constrain_kv(v)
         out = constrain_heads(blocked_causal_attention(q, k, v, scale,
                                                        q_chunk=q_chunk))
-        new_cache = None
+        new_kv = None
     else:
-        ck, cv = kv_cache["k"], kv_cache["v"]
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, cache_pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, cache_pos, 0, 0))
-        out = blocked_causal_attention(q, ck, cv, scale, q_offset=cache_pos,
-                                       q_chunk=min(q_chunk, x.shape[1]))
-        new_cache = {"k": ck, "v": cv}
+        new_kv = {"k": k.astype(kv_cache["k"].dtype),
+                  "v": v.astype(kv_cache["v"].dtype)}
+        empty = isinstance(cache_pos, int) and cache_pos == 0
+        out = blocked_causal_attention(
+            q, new_kv["k"], new_kv["v"], scale,
+            cache=None if empty else (kv_cache["k"], kv_cache["v"]),
+            cache_len=cache_pos, q_chunk=min(q_chunk, x.shape[1]))
     y = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), p["wo"])
-    return y, new_cache
+    return y, new_kv
+
+
+def write_cache(cache, new, cache_pos, layer0: int = 0):
+    """Write each leaf of `new` [n, B, S, ...] into the stacked `cache`
+    [L, B, T, ...] at layers layer0.. and positions cache_pos..: one
+    in-place update of the donated buffer per leaf."""
+    def put(c, n):
+        start = (layer0, 0, cache_pos) + (0,) * (c.ndim - 3)
+        return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), start)
+    return jax.tree_util.tree_map(put, cache, new)
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
@@ -261,7 +287,9 @@ def mla_fwd(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions,
             cache_pos: Optional[jnp.ndarray] = None):
     """MLA attention.  Prefill path expands K/V; decode path runs ABSORBED
     attention directly in the compressed latent space so the cache stays at
-    (kv_lora + rope) per token — the whole point of MLA."""
+    (kv_lora + rope) per token — the whole point of MLA.  With a cache, the
+    second result is the chunk's {ckv, krope} for the caller to write at
+    `cache_pos`, as `attention_fwd`'s is."""
     m = cfg.mla
     scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = _mla_q(p, x, cfg, positions)
@@ -303,7 +331,7 @@ def mla_fwd(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions,
         w = jax.nn.softmax(jnp.where(mask[None, None], scores, -1e30), axis=-1)
         lat = jnp.einsum("bhst,btr->bshr", w, cc.astype(jnp.float32))
         out = jnp.einsum("bshr,rhk->bshk", lat, p["wv_b"].astype(jnp.float32))
-        new_cache = {"ckv": cc, "krope": cr}
+        new_cache = {"ckv": ckv.astype(cc.dtype), "krope": k_rope.astype(cr.dtype)}
     y = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), p["wo"])
     return y, new_cache
 

@@ -128,7 +128,9 @@ def _init_hybrid_block(cfg: ModelConfig, key):
 
 def _apply_hybrid_block(cfg: ModelConfig, p: Params, h: jnp.ndarray, positions,
                         *, cache=None, cache_pos=None):
-    """cache (decode): {"kv": {k,v}, "conv": ..., "ssm": ...} for this block."""
+    """cache (decode): {"kv": {k,v}, "conv": ..., "ssm": ...} for this block.
+    The new cache holds the block's new SSM states and only the chunk's new
+    K/V under "kv", for the caller to write (`layers.write_cache`)."""
     hy = cfg.hybrid
     aux_total = jnp.float32(0.0)
     new_cache: Dict[str, Any] = {}
@@ -376,7 +378,7 @@ def _model_step(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
                                             cache=bc, cache_pos=cache_pos)
             return constrain_bsd(hh), nc
         h, nc = jax.lax.scan(body, h, (params["blocks"], cache))
-        new_cache = nc
+        new_cache = dict(nc, kv=L.write_cache(cache["kv"], nc["kv"], cache_pos))
     else:
         key = "mla" if cfg.mla is not None else "kv"
         # the stacked cache covers ALL layers; prefix layers use slots
@@ -391,19 +393,19 @@ def _model_step(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
 
 def _serve_tf(params, h, cfg, cache, cache_pos, positions):
     """Transformer serve path: prefix layers unrolled, rest scanned; the
-    stacked cache covers ALL layers (prefix first)."""
+    stacked cache covers ALL layers (prefix first).  The layers only read
+    the cache; each returns its new entries, and those are written into the
+    cache once, after the layers, so the donated buffer is updated in place
+    and never copied."""
     n_prefix = len(params.get("prefix", []))
     moe_rest = cfg.moe is not None
 
-    def take(tree, i):
-        return jax.tree_util.tree_map(lambda x: x[i], tree)
-
-    new_layers = []
+    prefix_new = []
     for i, lp in enumerate(params.get("prefix", [])):
-        c = take(cache, i)
+        c = jax.tree_util.tree_map(lambda x: x[i], cache)
         h, nc, _ = _apply_tf_layer(cfg, lp, h, positions, moe=False,
                                    cache=c, cache_pos=cache_pos)
-        new_layers.append(nc)
+        prefix_new.append(nc)
 
     rest_cache = jax.tree_util.tree_map(lambda x: x[n_prefix:], cache)
 
@@ -414,18 +416,15 @@ def _serve_tf(params, h, cfg, cache, cache_pos, positions):
         return constrain_bsd(hh), nc
     h, rest_new = jax.lax.scan(body, h, (params["blocks"], rest_cache))
 
-    if new_layers:
-        prefix_new = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *new_layers)
-        full = jax.tree_util.tree_map(
-            lambda a, b: jnp.concatenate([a, b], axis=0), prefix_new, rest_new)
-    else:
-        full = rest_new
-    return h, full
+    if prefix_new:
+        cache = L.write_cache(cache, _stack(prefix_new), cache_pos)
+    return h, L.write_cache(cache, rest_new, cache_pos, layer0=n_prefix)
 
 
 def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
             cache: Dict[str, Any]) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    return _model_step(params, batch, cfg, cache, jnp.int32(0))
+    # a Python 0: the cache holds nothing yet, so attention skips reading it
+    return _model_step(params, batch, cfg, cache, 0)
 
 
 def decode_step(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,

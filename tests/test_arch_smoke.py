@@ -141,3 +141,86 @@ def test_decode_matches_full_forward(arch):
     tol = 2e-1 if cfg.mla is not None else 2e-2
     np.testing.assert_allclose(np.asarray(full_logits), step_logits,
                                rtol=tol, atol=tol)
+
+
+def _attention_cache(cache):
+    """The position-indexed leaves of a serve cache, [L, B, T, ...] each."""
+    return jax.tree_util.tree_leaves(cache.get("kv", cache.get("mla")))
+
+
+def _prefill_then_decode(cfg, params, toks, s0, max_len):
+    """Prefill toks[:, :s0] into a fresh cache, then decode the rest
+    teacher-forced; returns each step's logits and the cache after it."""
+    logits, cache = prefill(params, {"tokens": toks[:, :s0]}, cfg,
+                            init_cache(cfg, toks.shape[0], max_len))
+    steps = [(logits, cache)]
+    for t in range(s0, toks.shape[1]):
+        logits, cache = decode_step(params, {"tokens": toks[:, t:t + 1]}, cfg,
+                                    cache, jnp.int32(t))
+        steps.append((logits, cache))
+    return steps
+
+
+@pytest.mark.parametrize("arch", [
+    "stablelm-3b",             # the chip benchmark's decode model
+    "chatglm3-6b",             # GQA: 4 query heads over 2 KV heads
+])
+def test_prefill_then_decode_matches_full_forward(arch):
+    """The serving path as `Server.generate` runs it: prefill a prompt into
+    a cache longer than the request, then decode teacher-forced.  Every
+    step's logits match the full-sequence forward, and the KV cache holds
+    what one prefill of the whole sequence writes below the last position
+    and zeros from it on."""
+    from repro.models import layers as L
+    from repro.models.transformer import forward
+    cfg = get_config(arch).reduced()
+    params, _ = init_model(cfg, jax.random.PRNGKey(0))
+    b, s0, s, max_len = 2, 8, 16, 24
+    toks = jax.random.randint(jax.random.PRNGKey(3), (b, s), 0, cfg.vocab_size)
+    h, _ = forward(params, {"tokens": toks}, cfg)
+    full_logits = np.asarray(L.lm_logits(params["embed"], h, cfg))
+
+    steps = _prefill_then_decode(cfg, params, toks, s0, max_len)
+    step_logits = np.stack([np.asarray(lg[:, 0]) for lg, _ in steps], axis=1)
+    np.testing.assert_allclose(full_logits[:, s0 - 1:], step_logits,
+                               rtol=2e-2, atol=2e-2)
+
+    _, ref = prefill(params, {"tokens": toks}, cfg, init_cache(cfg, b, max_len))
+    for got, want in zip(_attention_cache(steps[-1][1]), _attention_cache(ref)):
+        got = np.asarray(got.astype(jnp.float32))
+        want = np.asarray(want.astype(jnp.float32))
+        np.testing.assert_allclose(got[:, :, :s], want[:, :, :s],
+                                   rtol=2e-2, atol=2e-2)
+        assert not got[:, :, s:].any(), f"written past position {s - 1}"
+
+
+@pytest.mark.parametrize("arch", [
+    "stablelm-3b",
+    "chatglm3-6b",
+    "jamba-1.5-large-398b",    # hybrid blocks: one attention layer a block
+    "deepseek-v3-671b",        # MLA latent cache; 3 unrolled dense prefix layers
+])
+def test_serve_steps_write_only_their_positions(arch):
+    """Prefill writes positions 0..s0-1 of every layer's cache and nothing
+    beyond; each decode step at position t writes position t and leaves
+    every other entry of the cache as it was, bit for bit."""
+    cfg = get_config(arch).reduced()
+    params, _ = init_model(cfg, jax.random.PRNGKey(0))
+    b, s0, s, max_len = 2, 8, 12, 16
+    toks = jax.random.randint(jax.random.PRNGKey(3), (b, s), 0, cfg.vocab_size)
+    steps = _prefill_then_decode(cfg, params, toks, s0, max_len)
+    caches = [[np.asarray(x.astype(jnp.float32)) for x in _attention_cache(c)]
+              for _, c in steps]
+
+    def written(leaf):   # [L, T]: does layer l hold anything at position t
+        return np.abs(leaf).max(axis=(1, *range(3, leaf.ndim))) > 0
+
+    for leaf in caches[0]:
+        assert leaf.shape[2] == max_len
+        assert written(leaf)[:, :s0].all(), "prefill left a position unwritten"
+        assert not written(leaf)[:, s0:].any(), "prefill wrote past its prompt"
+    for t, (before, after) in enumerate(zip(caches, caches[1:]), start=s0):
+        for old, new in zip(before, after):
+            assert written(new)[:, t].all(), f"step {t} left a layer unwritten"
+            np.testing.assert_array_equal(np.delete(new, t, axis=2),
+                                          np.delete(old, t, axis=2))

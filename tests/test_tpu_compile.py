@@ -8,6 +8,7 @@ only one process at a time may load the TPU library, and every pytest
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +93,64 @@ def test_stablelm_serve_steps_fit_one_chip(one_chip, phase):
         tokens = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
         pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
         compiled = fn.lower(params, cache, {"tokens": tokens}, pos).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)")
+
+
+def _hlo_instrs(text):
+    """(name, dims, opcode, operand names) of every array-valued
+    instruction, those inside fused computations included."""
+    out = []
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m:
+            name, dims, op, args = m.groups()
+            out.append((name, tuple(int(d) for d in dims.split(",") if d), op,
+                        re.findall(r"%([\w.\-]+)", args)))
+    return out
+
+
+@pytest.mark.parametrize("phase,chunk", [("prefill", 512), ("decode", 1)])
+def test_stablelm_serve_steps_touch_only_new_cache_entries(one_chip, phase,
+                                                           chunk):
+    """The decode cell's shape: stablelm-3b full, batch 12, max_len 1024,
+    512-token prompts, the cache donated.  The steps read the KV cache where
+    it lies and write only the chunk's entries into it: no copy or transpose
+    of a layer's [B, max_len, Hkv, dh] slice or of the stacked cache, and
+    every update whose result has either shape writes a chunk of `chunk`
+    positions.  Decode then needs under 1 GiB of scratch."""
+    cfg = get_config("stablelm-3b")
+    b, max_len = 12, 1024
+    params = _on(abstract_state(cfg, AdamWConfig())["params"], one_chip)
+    cache = _on(jax.eval_shape(lambda: init_cache(cfg, b, max_len)), one_chip)
+    tokens = jax.ShapeDtypeStruct((b, chunk), jnp.int32, sharding=one_chip)
+    if phase == "prefill":
+        fn = jax.jit(lambda p, c, x: prefill_step(p, c, x, cfg),
+                     donate_argnums=(1,))
+        compiled = fn.lower(params, cache, {"tokens": tokens}).compile()
+    else:
+        fn = jax.jit(lambda p, c, x, pos: serve_step(p, c, x, pos, cfg),
+                     donate_argnums=(1,))
+        pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        compiled = fn.lower(params, cache, {"tokens": tokens}, pos).compile()
+
+    stacked = cache["kv"]["k"].shape                  # [L, B, T, Hkv, dh]
+    layer = stacked[1:]
+    cache_shapes = {stacked, layer, (1, *layer)}
+    new_entries = (stacked[0], b, chunk, *stacked[3:])
+    instrs = _hlo_instrs(compiled.as_text())
+    dims_of = {name: dims for name, dims, _, _ in instrs}
+    touched = [(name, op, dims_of.get(args[1]) if len(args) > 1 else None)
+               for name, dims, op, args in instrs if dims in cache_shapes
+               and op in ("copy", "transpose", "dynamic-update-slice")]
+    assert touched, "the cache update was not found in the compiled program"
+    assert all(op == "dynamic-update-slice" and update == new_entries
+               for _, op, update in touched), touched
+    if phase == "decode":
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**30
     assert _device_bytes(compiled) < HBM_BYTES
 
 
