@@ -41,7 +41,7 @@ def _moe_params(cfg: ModelConfig) -> Dict[str, int]:
     router = cfg.d_model * mo.n_experts
     shared = 3 * cfg.d_model * ff * mo.n_shared
     return {
-        "total": mo.n_experts * per_expert + router + shared,
+        "total": mo.held * per_expert + router + shared,
         "active": mo.top_k * per_expert + router + shared,
     }
 

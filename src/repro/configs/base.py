@@ -19,8 +19,15 @@ class MoEConfig:
     layer_period: int = 1          # MoE every `period` layers...
     n_dense_prefix: int = 0        # ...after this many leading dense layers
     router: str = "softmax"        # "softmax" | "sigmoid" (deepseek-v3)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # only where a mesh splits the experts
     router_scale: float = 1.0      # routed_scaling_factor
+    # expert parallelism: the router scores all `n_experts`; this chip holds
+    # (and computes) the first n_held of them, its share
+    n_held: int = 0                # 0 => every routed expert is held here
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -30,6 +37,20 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling as DeepSeek-V2 applies it (arXiv:2309.00071): the
+    rotary frequencies blend interpolated (/factor) and original ones over
+    a ramp that beta_fast/beta_slow set, and the softmax scale is multiplied
+    by mscale(mscale_all_dim)^2."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -68,6 +89,7 @@ class ModelConfig:
     pos_embed: str = "none"        # none | sinusoidal (absolute, musicgen)
     rope_fraction: float = 1.0     # partial rotary (chatglm: 0.5)
     rope_theta: float = 10000.0
+    yarn: Optional[YarnConfig] = None   # rope scaling of MLA's rope dims
     norm: str = "rmsnorm"          # rmsnorm | layernorm
     act: str = "silu"              # silu | gelu
     tie_embeddings: bool = False
@@ -92,9 +114,13 @@ class ModelConfig:
             d_ff=256, vocab_size=512, d_head=32, max_seq_len=4096,
         )
         if self.moe is not None:
-            small["moe"] = replace(self.moe, n_experts=min(8, self.moe.n_experts),
+            e = min(8, self.moe.n_experts)
+            small["moe"] = replace(self.moe, n_experts=e,
                                    top_k=min(2, self.moe.top_k),
-                                   d_expert_ff=128 if self.moe.d_expert_ff else 0)
+                                   d_expert_ff=128 if self.moe.d_expert_ff else 0,
+                                   n_held=(max(1, self.moe.n_held * e
+                                               // self.moe.n_experts)
+                                           if self.moe.n_held else 0))
         if self.mla is not None:
             small["mla"] = MLAConfig(kv_lora_rank=64,
                                      q_lora_rank=32 if self.mla.q_lora_rank else 0,

@@ -44,15 +44,16 @@ class Server:
             donate_argnums=(1,))
         self.batches_served = 0
 
-    def _embed_stub(self, tokens: np.ndarray) -> Optional[np.ndarray]:
+    def _embed_stub(self, tokens) -> Optional[np.ndarray]:
         """Stub modality frontend: deterministic pseudo-embeddings per token
-        (audio/vlm archs take precomputed frame/patch embeddings)."""
+        (audio/vlm archs take precomputed frame/patch embeddings).  Only
+        these archs bring the tokens to the host."""
         if self.cfg.frontend is None:
             return None
         rng = np.random.default_rng(1234)
         table = rng.standard_normal((self.cfg.vocab_size, self.cfg.d_model),
                                     dtype=np.float32) * 0.02
-        return table[tokens]
+        return table[np.asarray(tokens)]
 
     def generate(self, prompts: np.ndarray, n_tokens: int
                  ) -> Dict[str, np.ndarray]:
@@ -76,19 +77,23 @@ class Server:
             outs: List[np.ndarray] = []
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             t0 = time.perf_counter()
+            # Token i goes to the host only after the step that consumes it
+            # is dispatched, so the device runs that step while the host
+            # waits: the host's per-step time stays off the device's path.
             for i in range(n_tokens):
                 with obs.span("serve.decode_step", token=i):
-                    with obs.span("serve.token_sync"):
-                        outs.append(np.asarray(tok))
                     with obs.span("serve.dispatch"):
                         step_batch = {"tokens": tok[:, None]}
-                        emb = self._embed_stub(np.asarray(tok)[:, None])
+                        emb = self._embed_stub(step_batch["tokens"])
                         if emb is not None:
                             step_batch["embeds"] = jnp.asarray(emb, jnp.bfloat16)
                         logits, cache = self._decode(self.params, cache,
                                                      step_batch,
                                                      jnp.int32(s0 + i))
-                        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                    with obs.span("serve.token_sync"):
+                        outs.append(np.asarray(tok))
+                    tok = nxt
             with obs.span("serve.token_sync"):
                 tok.block_until_ready()
             decode_s = time.perf_counter() - t0

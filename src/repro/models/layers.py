@@ -15,7 +15,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..configs.base import MLAConfig, ModelConfig, MoEConfig
+from ..configs.base import MLAConfig, ModelConfig, MoEConfig, YarnConfig
 from ..context import constrain, constrain_heads, constrain_kv
 
 Params = Dict[str, Any]
@@ -64,12 +64,35 @@ def apply_norm(p: Params, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
 # rotary / positional embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_freqs(dim: int, theta: float, yarn: Optional[YarnConfig] = None
+               ) -> jnp.ndarray:
+    """Rotary frequencies of `dim` rotated dims.  With YaRN (DeepSeek-V2's
+    form), frequency i is the original one for i up to the dimension that
+    turns `beta_fast` times over the original window, the original over
+    `factor` from the one that turns `beta_slow` times, and a linear blend
+    of the two between."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if yarn is None:
+        return freqs
+
+    def dim_at(turns):
+        return (dim * math.log(yarn.original_max_position
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_at(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_at(yarn.beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / ((high - low) or 0.001), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-               fraction: float = 1.0) -> jnp.ndarray:
+               fraction: float = 1.0, yarn: Optional[YarnConfig] = None
+               ) -> jnp.ndarray:
     """x: [..., S, H, dh]; positions: [..., S] (broadcastable)."""
     dh = x.shape[-1]
     rot = int(dh * fraction)
@@ -77,9 +100,13 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
     if rot == 0:
         return x
     x_rot, x_pass = x[..., :rot], x[..., rot:]
-    freqs = rope_freqs(rot, theta)                       # [rot/2]
+    freqs = rope_freqs(rot, theta, yarn)                 # [rot/2]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [..., S, rot/2]
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        mag = (yarn_mscale(yarn.factor, yarn.mscale)
+               / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * mag, sin * mag
     x1, x2 = jnp.split(x_rot.astype(jnp.float32), 2, axis=-1)
     y = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return jnp.concatenate([y.astype(x.dtype), x_pass], axis=-1)
@@ -133,13 +160,19 @@ def blocked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     With `cache` = (ck, cv) [B,T,Hkv,dh], q/k/v are a new chunk that follows
     the cache's first `cache_len` entries: every query also attends to those,
     read in place.  One softmax spans the cache block and the chunk block.
+
+    Any Sq works: the queries split into the fewest chunks of at most
+    `q_chunk`, all of one length, the last padded with queries whose rows
+    are dropped from the result.
     """
     b, sq, h, dh = q.shape
     hkv = k.shape[2]
     rep = h // hkv
-    q_chunk = min(q_chunk, sq)
-    assert sq % q_chunk == 0
-    nchunks = sq // q_chunk
+    nchunks = -(-sq // q_chunk)
+    q_chunk = -(-sq // nchunks)
+    pad = nchunks * q_chunk - sq
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
     qg = q.reshape(b, nchunks, q_chunk, hkv, rep, dh).astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
@@ -166,7 +199,7 @@ def blocked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     out = jax.lax.map(one_chunk, jnp.arange(nchunks))           # [NC,B,qc,G,R,dv]
     dv = v.shape[-1]  # may differ from q/k head dim (MLA)
-    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, h, dv)
+    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq + pad, h, dv)[:, :sq]
     # cast back to the storage dtype at the boundary: keeps the fwd output
     # AND its backward cotangent chain (the TP partial-sum all-reduces) in
     # bf16 instead of f32 — halves the dominant collective (§Perf iter-3)
@@ -211,7 +244,7 @@ def attention_fwd(p: Params, x: jnp.ndarray, cfg: ModelConfig,
         out = blocked_causal_attention(
             q, new_kv["k"], new_kv["v"], scale,
             cache=None if empty else (kv_cache["k"], kv_cache["v"]),
-            cache_len=cache_pos, q_chunk=min(q_chunk, x.shape[1]))
+            cache_len=cache_pos, q_chunk=q_chunk)
     y = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), p["wo"])
     return y, new_kv
 
@@ -269,7 +302,7 @@ def init_mla(cfg: ModelConfig, key) -> Tuple[Params, PyTree]:
     return p, a
 
 
-def _mla_q(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions) :
+def _mla_q(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions):
     m = cfg.mla
     if m.q_lora_rank:
         cq = jnp.einsum("bsd,dr->bsr", x, p["wq_a"])
@@ -278,60 +311,91 @@ def _mla_q(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions) :
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, yarn=cfg.yarn)
     return q_nope, q_rope
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk head dim), times mscale(mscale_all_dim)^2 under YaRN."""
+    m, y = cfg.mla, cfg.yarn
+    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def mla_fwd(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions,
             *, kv_cache: Optional[Dict[str, jnp.ndarray]] = None,
-            cache_pos: Optional[jnp.ndarray] = None):
-    """MLA attention.  Prefill path expands K/V; decode path runs ABSORBED
-    attention directly in the compressed latent space so the cache stays at
-    (kv_lora + rope) per token — the whole point of MLA.  With a cache, the
-    second result is the chunk's {ckv, krope} for the caller to write at
-    `cache_pos`, as `attention_fwd`'s is."""
+            cache_pos=None):
+    """MLA attention.  Without a cache (training) and in prefill (a Python-0
+    `cache_pos`: nothing is cached yet) K and V are expanded from the latent
+    and attention runs blocked over [nope | rope] heads.  In decode the
+    query is absorbed into the latent space (q_nope W_uk), so attention
+    reads the cache's (kv_lora + rope) entries of each token where they lie
+    in the stacked cache, and scores the chunk's own new entries beside them
+    under one softmax.  With a cache, the second result is the chunk's
+    {ckv, krope} in the cache's dtype, for the caller to write at
+    `cache_pos` (see `write_cache`), as `attention_fwd`'s is."""
     m = cfg.mla
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = mla_softmax_scale(cfg)
     q_nope, q_rope = _mla_q(p, x, cfg, positions)
 
     ckv_full = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
     ckv, k_rope_raw = ckv_full[..., : m.kv_lora_rank], ckv_full[..., m.kv_lora_rank:]
     ckv = apply_norm({"scale": p["kv_norm"]}, ckv)
-    k_rope = apply_rope(k_rope_raw[..., None, :], positions, cfg.rope_theta)[..., 0, :]
+    k_rope = apply_rope(k_rope_raw[..., None, :], positions, cfg.rope_theta,
+                        yarn=cfg.yarn)[..., 0, :]
+    new_cache = None
+    if kv_cache is not None:
+        new_cache = {"ckv": ckv.astype(kv_cache["ckv"].dtype),
+                     "krope": k_rope.astype(kv_cache["krope"].dtype)}
+        ckv, k_rope = new_cache["ckv"], new_cache["krope"]
 
-    if kv_cache is None:
+    if kv_cache is None or (isinstance(cache_pos, int) and cache_pos == 0):
         # expand K/V and run blocked attention with concatenated
-        # [nope | rope] head dims (rope part broadcast across heads)
+        # [nope | rope] head dims (rope part broadcast across heads); the
+        # expanded K and V stay in float32, as decode's absorbed products do
         h = cfg.n_heads
-        k_nope = jnp.einsum("bsr,rhk->bshk", ckv, p["wk_b"])
-        v = jnp.einsum("bsr,rhk->bshk", ckv, p["wv_b"])
+        k_nope = jnp.einsum("bsr,rhk->bshk", ckv, p["wk_b"],
+                            preferred_element_type=jnp.float32)
+        v = jnp.einsum("bsr,rhk->bshk", ckv, p["wv_b"],
+                       preferred_element_type=jnp.float32)
+        k_rope = k_rope.astype(jnp.float32)
         q_cat = constrain_heads(jnp.concatenate([q_nope, q_rope], axis=-1))
         k_cat = constrain_heads(jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                       (*k_rope.shape[:2], h, m.qk_rope_dim))],
             axis=-1))
-        out = constrain_heads(
-            blocked_causal_attention(q_cat, k_cat, constrain_heads(v), scale))
-        new_cache = None
+        # prefill takes 256-query chunks: a 4,096-token prefill of 16 rows
+        # then holds 1 GB of f32 scores a chunk, not 2 GB, and fits a v5e
+        # beside its cache; training keeps the default 512
+        with jax.named_scope("mla.attend"):
+            out = constrain_heads(blocked_causal_attention(
+                q_cat, k_cat, constrain_heads(v), scale,
+                q_chunk=512 if kv_cache is None else 256))
     else:
-        cc, cr = kv_cache["ckv"], kv_cache["krope"]
-        cc = jax.lax.dynamic_update_slice(cc, ckv.astype(cc.dtype),
-                                          (0, cache_pos, 0))
-        cr = jax.lax.dynamic_update_slice(cr, k_rope.astype(cr.dtype),
-                                          (0, cache_pos, 0))
+        f32 = jnp.float32
+        cc, cr = kv_cache["ckv"], kv_cache["krope"]         # [B,T,r], [B,T,dr]
         # absorption: q' = W_uk^T q_nope lives in the latent space
-        q_lat = jnp.einsum("bshk,rhk->bshr", q_nope.astype(jnp.float32),
-                           p["wk_b"].astype(jnp.float32))
-        scores = (jnp.einsum("bshr,btr->bhst", q_lat, cc.astype(jnp.float32))
-                  + jnp.einsum("bshk,btk->bhst", q_rope.astype(jnp.float32),
-                               cr.astype(jnp.float32))) * scale
-        t_idx = jnp.arange(cc.shape[1])
-        q_idx = cache_pos + jnp.arange(x.shape[1])
-        mask = t_idx[None, :] <= q_idx[:, None]
-        w = jax.nn.softmax(jnp.where(mask[None, None], scores, -1e30), axis=-1)
-        lat = jnp.einsum("bhst,btr->bshr", w, cc.astype(jnp.float32))
-        out = jnp.einsum("bshr,rhk->bshk", lat, p["wv_b"].astype(jnp.float32))
-        new_cache = {"ckv": ckv.astype(cc.dtype), "krope": k_rope.astype(cr.dtype)}
+        q_lat = jnp.einsum("bshk,rhk->bshr", q_nope.astype(f32),
+                           p["wk_b"].astype(f32))
+        qr = q_rope.astype(f32)
+        with jax.named_scope("mla.attend"):
+            s_cache = (jnp.einsum("bshr,btr->bhst", q_lat, cc.astype(f32))
+                       + jnp.einsum("bshk,btk->bhst", qr, cr.astype(f32))) * scale
+            s_cache = jnp.where(jnp.arange(cc.shape[1]) < cache_pos, s_cache,
+                                -1e30)
+            s_new = (jnp.einsum("bshr,btr->bhst", q_lat, ckv.astype(f32))
+                     + jnp.einsum("bshk,btk->bhst", qr, k_rope.astype(f32))) * scale
+            sq = x.shape[1]
+            causal = jnp.arange(sq)[None, :] <= jnp.arange(sq)[:, None]
+            s_new = jnp.where(causal, s_new, -1e30)
+            w = jax.nn.softmax(jnp.concatenate([s_cache, s_new], axis=-1),
+                               axis=-1)
+            t = cc.shape[1]
+            lat = (jnp.einsum("bhst,btr->bshr", w[..., :t], cc.astype(f32))
+                   + jnp.einsum("bhst,btr->bshr", w[..., t:], ckv.astype(f32)))
+        out = jnp.einsum("bshr,rhk->bshk", lat, p["wv_b"].astype(f32))
     y = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), p["wo"])
     return y, new_cache
 
@@ -390,12 +454,12 @@ def init_moe(cfg: ModelConfig, key):
     d = cfg.d_model
     ff = mo.d_expert_ff or cfg.d_ff
     ks = jax.random.split(key, 5)
-    e = mo.n_experts
+    e, n = mo.n_experts, mo.held   # the router scores all; n are held here
     p = {
         "router": _dense_init(ks[0], (d, e), d, dtype=jnp.float32),
-        "wi_gate": _dense_init(ks[1], (e, d, ff), d),
-        "wi_up": _dense_init(ks[2], (e, d, ff), d),
-        "wo": _dense_init(ks[3], (e, ff, d), ff),
+        "wi_gate": _dense_init(ks[1], (n, d, ff), d),
+        "wi_up": _dense_init(ks[2], (n, d, ff), d),
+        "wo": _dense_init(ks[3], (n, ff, d), ff),
     }
     a = {
         "router": ("embed", "experts_nosplit"),
@@ -412,70 +476,159 @@ def init_moe(cfg: ModelConfig, key):
     return p, a
 
 
-def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Capacity-based top-k MoE.  Returns (y, aux_loss)."""
-    mo: MoEConfig = cfg.moe
-    b, s, d = x.shape
-    t = b * s
-    e, k = mo.n_experts, mo.top_k
-    xt = x.reshape(t, d)
+MOE_TOKEN_CHUNK = 4096    # a chunk's pairs: 24,576 rows of d, 100 MB at 2048
 
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
-    if mo.router == "sigmoid":           # deepseek-v3 gating
-        scores = jax.nn.sigmoid(logits)
-        sel_scores = scores + p["router_bias"]     # bias for load balance only
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
-        sel_scores = scores
-    _, top_idx = jax.lax.top_k(sel_scores, k)                     # [t, k]
-    top_w = jnp.take_along_axis(scores, top_idx, axis=-1)         # [t, k]
-    if mo.router == "sigmoid":
-        top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-9)
-    top_w = top_w * mo.router_scale
 
-    # load-balancing aux loss (switch-style) without materializing [t,k,e]:
-    # fraction of assignments per expert via bincount
-    flat_e = top_idx.reshape(-1)                                   # [t*k] int32
-    counts = jnp.zeros((e,), jnp.float32).at[flat_e].add(1.0)
-    me = counts / t
-    ce = scores.mean(0)
-    aux = (me * ce).sum() * e / k
+def _held_experts(p: Params, xt: jnp.ndarray, top_idx: jnp.ndarray,
+                  top_w: jnp.ndarray, mo: MoEConfig) -> jnp.ndarray:
+    """The held experts' part of the layer for tokens xt [t, d] routed to
+    top_idx [t, k] (router-wide ids; the held experts are the first
+    `mo.held`) with weights top_w: every pair on a held expert is computed.
+    Returns [t, d] float32."""
+    t, d = xt.shape
+    k = top_idx.shape[1]
+    n = mo.held
+    held = top_idx < n
+    gid = jnp.where(held, top_idx, n).reshape(-1)          # n: held elsewhere
+    order = jnp.argsort(gid, stable=True)                  # [t*k], held first
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=order.dtype))
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[gid].add(1)[:n]
+    xs = xt[order // k]                                    # [t*k, d]
+    g = jax.lax.ragged_dot(xs, p["wi_gate"], sizes)
+    u = jax.lax.ragged_dot(xs, p["wi_up"], sizes)
+    hid = (jax.nn.silu(g.astype(jnp.float32)).astype(xt.dtype) * u)
+    eo = jax.lax.ragged_dot(hid, p["wo"], sizes)           # [t*k, d]
+    # rows past the held pairs belong to no group: zero them explicitly
+    eo = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], eo, 0)
+    w = jnp.where(held, top_w, 0.0).astype(jnp.float32)
+    return (eo[inv].reshape(t, k, d).astype(jnp.float32)
+            * w[..., None]).sum(axis=1)
+
+
+def _dropless(p: Params, xt: jnp.ndarray, top_idx: jnp.ndarray,
+              top_w: jnp.ndarray, mo: MoEConfig) -> jnp.ndarray:
+    """`_held_experts` over chunks of at most MOE_TOKEN_CHUNK tokens, all of
+    one length; the last is padded with tokens routed to no held expert."""
+    t, d = xt.shape
+    k = top_idx.shape[1]
+    nc = -(-t // MOE_TOKEN_CHUNK)
+    if nc == 1:
+        return _held_experts(p, xt, top_idx, top_w, mo)
+    c = -(-t // nc)
+    pad = nc * c - t
+    if pad:
+        xt = jnp.pad(xt, ((0, pad), (0, 0)))
+        top_idx = jnp.pad(top_idx, ((0, pad), (0, 0)), constant_values=mo.held)
+        top_w = jnp.pad(top_w, ((0, pad), (0, 0)))
+    y = jax.lax.map(lambda a: _held_experts(p, *a, mo),
+                    (xt.reshape(nc, c, d), top_idx.reshape(nc, c, k),
+                     top_w.reshape(nc, c, k)))
+    return y.reshape(nc * c, d)[:t]
+
+
+def _with_capacity(p: Params, xt: jnp.ndarray, top_idx: jnp.ndarray,
+                   top_w: jnp.ndarray, mo: MoEConfig) -> jnp.ndarray:
+    """The held experts' part with a fixed capacity per expert: each expert
+    takes at most ceil(t k / n_experts * capacity_factor) of its pairs, in
+    token order, and drops the rest.  The [experts, capacity, d] buffers
+    partition along an expert-sharded mesh axis.  Returns [t, d] float32."""
+    t, d = xt.shape
+    k = top_idx.shape[1]
+    e, n = mo.n_experts, mo.held
+    held = top_idx < n
+    top_idx = jnp.where(held, top_idx, n)    # held elsewhere: sorts last
 
     # ---- position-in-expert via 1-D sort (O(t*k) memory, not O(t*k*e)) ----
+    flat_e = top_idx.reshape(-1)
+    counts = jnp.zeros((n + 1,), jnp.int32).at[flat_e].add(1)
     capacity = int(max(1, math.ceil(t * k / e * mo.capacity_factor)))
     order = jnp.argsort(flat_e, stable=True)                       # [t*k]
     ranks = jnp.zeros((t * k,), jnp.int32).at[order].set(
         jnp.arange(t * k, dtype=jnp.int32))
-    offsets = jnp.cumsum(counts.astype(jnp.int32)) - counts.astype(jnp.int32)
+    offsets = jnp.cumsum(counts) - counts
     pos_flat = ranks - offsets[flat_e]                             # [t*k]
-    keep = (pos_flat < capacity).reshape(t, k)
+    keep = (pos_flat < capacity).reshape(t, k) & held
     pos = jnp.clip(pos_flat, 0, capacity - 1).reshape(t, k)
+    top_idx = jnp.minimum(top_idx, n - 1)
 
     # ---- dispatch: k sequential scatters, each reading xt in place ----
-    # buf/eo constrained expert-sharded ("ecd") so the scatter lowers as the
-    # token->expert all-to-all and every expert FFN computes locally (§Perf)
-    buf = jnp.zeros((e, capacity, d), xt.dtype)
+    buf = jnp.zeros((n, capacity, d), xt.dtype)
     for j in range(k):
         src = xt * keep[:, j : j + 1].astype(xt.dtype)
         buf = buf.at[top_idx[:, j], pos[:, j]].add(src)
-    buf = constrain(buf, "ecd")
 
     # expert FFNs: [e, c, d] x [e, d, f]; silu in fp32, product kept bf16
     # (the [e, capacity, ff] intermediates dominate MoE activation memory)
-    g = jax.nn.silu(constrain(jnp.einsum("ecd,edf->ecf", buf, p["wi_gate"]),
-                              "ecd").astype(jnp.float32)).astype(xt.dtype)
-    u = constrain(jnp.einsum("ecd,edf->ecf", buf, p["wi_up"]), "ecd")
-    eo = constrain(jnp.einsum("ecf,efd->ecd", g * u, p["wo"]), "ecd")
+    g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, p["wi_gate"]
+                               ).astype(jnp.float32)).astype(xt.dtype)
+    u = jnp.einsum("ecd,edf->ecf", buf, p["wi_up"])
+    eo = jnp.einsum("ecf,efd->ecd", g * u, p["wo"])
 
     # ---- combine: k gathers, weighted accumulation ----
     y = jnp.zeros((t, d), jnp.float32)
     for j in range(k):
         w = (top_w[:, j] * keep[:, j]).astype(jnp.float32)
         y = y + eo[top_idx[:, j], pos[:, j]].astype(jnp.float32) * w[:, None]
+    return y
+
+
+def _experts_split() -> bool:
+    """Whether the jit traces under a mesh that splits the experts: their
+    logical axis's mesh axis has more than one device."""
+    from ..runtime.sharding import LOGICAL_TO_MESH   # imports the models
+    mesh = jax.sharding.get_abstract_mesh()
+    axis = LOGICAL_TO_MESH["experts"]
+    return not mesh.empty and mesh.shape.get(axis, 1) > 1
+
+
+def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Top-k MoE over the routed experts held here, plus the shared experts.
+    The router scores all `n_experts`; pairs on experts held elsewhere add
+    nothing here (under expert parallelism their chips add them).  Shared
+    experts run for every token.  Returns (y, aux_loss).
+
+    On one device the layer is dropless: each pair on a held expert is
+    computed, the pairs sorted by expert and each expert weight one grouped
+    product over the held experts (`lax.ragged_dot`), in token chunks of at
+    most MOE_TOKEN_CHUNK.  Under a mesh that splits the experts, GSPMD runs
+    `ragged_dot` on every shard's tokens and all-reduces the result (1.7x
+    the temporaries and all-reduce bytes of a train step on a 4-way expert
+    split), so there each expert takes a fixed capacity of pairs instead
+    and the [experts, capacity, d] buffers partition with the experts."""
+    mo: MoEConfig = cfg.moe
+    b, s, d = x.shape
+    t = b * s
+    e, k = mo.n_experts, mo.top_k
+    xt = x.reshape(t, d)
+
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
+        if mo.router == "sigmoid":           # deepseek-v3 gating
+            scores = jax.nn.sigmoid(logits)
+            sel_scores = scores + p["router_bias"]  # bias for load balance only
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+            sel_scores = scores
+        _, top_idx = jax.lax.top_k(sel_scores, k)                 # [t, k]
+        top_w = jnp.take_along_axis(scores, top_idx, axis=-1)     # [t, k]
+        if mo.router == "sigmoid":
+            top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-9)
+        top_w = top_w * mo.router_scale
+
+        # load-balancing aux loss (switch-style) from the assignments per
+        # expert, without materializing [t, k, e]
+        counts = jnp.zeros((e,), jnp.float32).at[top_idx.reshape(-1)].add(1.0)
+        aux = (counts / t * scores.mean(0)).sum() * e / k
+
+    with jax.named_scope("moe.experts"):
+        routed = _with_capacity if _experts_split() else _dropless
+        y = routed(p, xt, top_idx, top_w, mo)
 
     if mo.n_shared:
-        y = y + apply_mlp(p["shared"], xt[None], cfg)[0].astype(jnp.float32)
+        with jax.named_scope("moe.shared"):
+            y = y + apply_mlp(p["shared"], xt[None], cfg)[0].astype(jnp.float32)
     return y.reshape(b, s, d).astype(x.dtype), aux
 
 

@@ -400,21 +400,23 @@ def _serve_tf(params, h, cfg, cache, cache_pos, positions):
     n_prefix = len(params.get("prefix", []))
     moe_rest = cfg.moe is not None
 
+    def layer_cache(i):      # layer i's slice, read where it lies
+        return jax.tree_util.tree_map(lambda x: x[i], cache)
+
     prefix_new = []
     for i, lp in enumerate(params.get("prefix", [])):
-        c = jax.tree_util.tree_map(lambda x: x[i], cache)
         h, nc, _ = _apply_tf_layer(cfg, lp, h, positions, moe=False,
-                                   cache=c, cache_pos=cache_pos)
+                                   cache=layer_cache(i), cache_pos=cache_pos)
         prefix_new.append(nc)
 
-    rest_cache = jax.tree_util.tree_map(lambda x: x[n_prefix:], cache)
-
     def body(hh, xs):
-        lp, c = xs
+        lp, i = xs
         hh, nc, _ = _apply_tf_layer(cfg, lp, hh, positions, moe=moe_rest,
-                                    cache=c, cache_pos=cache_pos)
+                                    cache=layer_cache(i), cache_pos=cache_pos)
         return constrain_bsd(hh), nc
-    h, rest_new = jax.lax.scan(body, h, (params["blocks"], rest_cache))
+    n_rest = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
+    h, rest_new = jax.lax.scan(body, h, (params["blocks"],
+                                         n_prefix + jnp.arange(n_rest)))
 
     if prefix_new:
         cache = L.write_cache(cache, _stack(prefix_new), cache_pos)

@@ -160,7 +160,7 @@ def activation_specs_for(mesh: Mesh, shape: InputShape,
     m = mesh.shape.get("model", 1)
     bsp = batch_spec(mesh, shape.global_batch, shape.seq_len)
     bdim = tuple(bsp)[0] if len(tuple(bsp)) else None
-    heads = kv = ecd = None
+    heads = kv = None
     if cfg is not None and m > 1 and shape.kind in ("train", "prefill"):
         # the seq->head transition is only coherent when BOTH q and kv heads
         # can take the model axis; constraining q alone while k/v stay
@@ -175,12 +175,12 @@ def activation_specs_for(mesh: Mesh, shape: InputShape,
     # full-ff layouts on [B,1,ff] regressed every decode cell (§Perf iter-7)
     bsf = bsd if shape.kind in ("train", "prefill") else None
     # NOTE (§Perf iter-4, REFUTED): constraining the MoE dispatch buffers to
-    # P("model", None, None) makes GSPMD replicate the data-dependent scatter
+    # P("model", None, None) made GSPMD replicate the data-dependent scatter
     # on every shard and mask+all-reduce the result (measured 2.2x worse:
     # collective 43s->96s, compute 0.56s->4.0s on deepseek-v2-lite train_4k).
     # A ragged shard_map all-to-all is the correct implementation; until
-    # then the dispatch stays unconstrained.  `ecd` intentionally None.
-    return {"bsd": bsd, "bsf": bsf, "heads": heads, "kv": kv, "ecd": ecd}
+    # then the capacity dispatch's buffers stay unconstrained.
+    return {"bsd": bsd, "bsf": bsf, "heads": heads, "kv": kv}
 
 
 def activation_spec_for(mesh: Mesh, shape: InputShape) -> P:

@@ -246,3 +246,40 @@ def test_server_generate_one_decode_step_per_token(tracer):
             assert sum(r.parent in ids for r in names[child]) == n
         assert [r.parent for r in names["serve.prefill"]].count(g.id) == 1
         assert [r.parent for r in names["serve.init_cache"]].count(g.id) == 1
+
+
+def test_server_reads_each_token_after_dispatching_its_step(tracer):
+    """Within every decode step the host dispatches the next step before it
+    waits for the token, and the tokens are the greedy ones that a loop
+    which reads each token first produces.  An arch without a frontend stub
+    never reads the token while dispatching."""
+    import jax.numpy as jnp
+    from repro.launch.serve import Server
+    from repro.models import init_cache
+    srv = Server("stablelm-3b", reduced=True, max_len=32)
+    prompts = np.random.default_rng(1).integers(
+        1, srv.cfg.vocab_size, size=(2, 8)).astype(np.int32)
+    out = srv.generate(prompts, 6)
+    records, _ = obs.drain()
+    by_id = {r.id: r for r in records}
+    for step in (r for r in records if r.name == "serve.decode_step"):
+        kids = {by_id[i].name: by_id[i] for i in by_id
+                if by_id[i].parent == step.id}
+        assert (kids["serve.dispatch"].end_ns
+                <= kids["serve.token_sync"].start_ns)
+
+    logits, cache = srv._prefill(srv.params, init_cache(srv.cfg, 2, 32),
+                                 {"tokens": jnp.asarray(prompts)})
+    want = []
+    for i in range(6):
+        tok = np.asarray(jnp.argmax(logits[:, -1], axis=-1)).astype(np.int32)
+        want.append(tok)
+        logits, cache = srv._decode(srv.params, cache,
+                                    {"tokens": jnp.asarray(tok)[:, None]},
+                                    jnp.int32(8 + i))
+    np.testing.assert_array_equal(out["tokens"], np.stack(want, 1))
+
+    class NotOnHost:             # a token array the host must not read
+        def __array__(self, *a, **k):
+            raise AssertionError("token brought to the host")
+    assert srv._embed_stub(NotOnHost()) is None

@@ -1,6 +1,7 @@
 """Compile the main-path programs for a TPU v5e chip that is described, not
 attached: the trainer's step and the server's prefill and decode at full
-width, and the Pallas RMSNorm kernel at the model widths.  Nothing runs;
+width (stablelm-3b, and the DeepSeek-V2-Lite chip share at its cell's
+shapes), and the Pallas RMSNorm kernel at the model widths.  Nothing runs;
 the TPU compiler refuses what the chip could not run (tiling, VMEM, HBM).
 
 The topology is described inside a module-scoped fixture, never at import:
@@ -113,6 +114,41 @@ def _hlo_instrs(text):
     return out
 
 
+def _compile_serve_step(cfg, one_chip, phase, b, chunk, max_len):
+    """The server's prefill or decode step for `cfg`, the cache donated,
+    compiled for one described chip; and the abstract cache."""
+    params = _on(abstract_state(cfg, AdamWConfig())["params"], one_chip)
+    cache = _on(jax.eval_shape(lambda: init_cache(cfg, b, max_len)), one_chip)
+    tokens = jax.ShapeDtypeStruct((b, chunk), jnp.int32, sharding=one_chip)
+    if phase == "prefill":
+        fn = jax.jit(lambda p, c, x: prefill_step(p, c, x, cfg),
+                     donate_argnums=(1,))
+        return fn.lower(params, cache, {"tokens": tokens}).compile(), cache
+    fn = jax.jit(lambda p, c, x, pos: serve_step(p, c, x, pos, cfg),
+                 donate_argnums=(1,))
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return fn.lower(params, cache, {"tokens": tokens}, pos).compile(), cache
+
+
+def _cache_touches(compiled, leaves, b, chunk, layer_runs):
+    """Every copy, transpose or update of a stacked cache leaf [L, B, T,
+    ...], of one layer's slice of it or of that slice with a unit layer
+    axis, as (name, op, shape of the update); and the shapes [n, B, chunk,
+    ...] of an update that writes only `chunk` positions of a run of n
+    layers, n in `layer_runs` (the unrolled prefix, the scanned rest)."""
+    cache_shapes, new_entries = set(), set()
+    for stacked in leaves:
+        layer = stacked[1:]
+        cache_shapes |= {stacked, layer, (1, *layer)}
+        new_entries |= {(n, b, chunk, *stacked[3:]) for n in layer_runs}
+    instrs = _hlo_instrs(compiled.as_text())
+    dims_of = {name: dims for name, dims, _, _ in instrs}
+    touched = [(name, op, dims_of.get(args[1]) if len(args) > 1 else None)
+               for name, dims, op, args in instrs if dims in cache_shapes
+               and op in ("copy", "transpose", "dynamic-update-slice")]
+    return touched, new_entries
+
+
 @pytest.mark.parametrize("phase,chunk", [("prefill", 512), ("decode", 1)])
 def test_stablelm_serve_steps_touch_only_new_cache_entries(one_chip, phase,
                                                            chunk):
@@ -123,34 +159,39 @@ def test_stablelm_serve_steps_touch_only_new_cache_entries(one_chip, phase,
     every update whose result has either shape writes a chunk of `chunk`
     positions.  Decode then needs under 1 GiB of scratch."""
     cfg = get_config("stablelm-3b")
-    b, max_len = 12, 1024
-    params = _on(abstract_state(cfg, AdamWConfig())["params"], one_chip)
-    cache = _on(jax.eval_shape(lambda: init_cache(cfg, b, max_len)), one_chip)
-    tokens = jax.ShapeDtypeStruct((b, chunk), jnp.int32, sharding=one_chip)
-    if phase == "prefill":
-        fn = jax.jit(lambda p, c, x: prefill_step(p, c, x, cfg),
-                     donate_argnums=(1,))
-        compiled = fn.lower(params, cache, {"tokens": tokens}).compile()
-    else:
-        fn = jax.jit(lambda p, c, x, pos: serve_step(p, c, x, pos, cfg),
-                     donate_argnums=(1,))
-        pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-        compiled = fn.lower(params, cache, {"tokens": tokens}, pos).compile()
-
-    stacked = cache["kv"]["k"].shape                  # [L, B, T, Hkv, dh]
-    layer = stacked[1:]
-    cache_shapes = {stacked, layer, (1, *layer)}
-    new_entries = (stacked[0], b, chunk, *stacked[3:])
-    instrs = _hlo_instrs(compiled.as_text())
-    dims_of = {name: dims for name, dims, _, _ in instrs}
-    touched = [(name, op, dims_of.get(args[1]) if len(args) > 1 else None)
-               for name, dims, op, args in instrs if dims in cache_shapes
-               and op in ("copy", "transpose", "dynamic-update-slice")]
+    b = 12
+    compiled, cache = _compile_serve_step(cfg, one_chip, phase, b, chunk, 1024)
+    touched, new_entries = _cache_touches(compiled, [cache["kv"]["k"].shape],
+                                          b, chunk, [cfg.n_layers])
     assert touched, "the cache update was not found in the compiled program"
-    assert all(op == "dynamic-update-slice" and update == new_entries
+    assert all(op == "dynamic-update-slice" and update in new_entries
                for _, op, update in touched), touched
     if phase == "decode":
         assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("phase,chunk", [("prefill", 4096), ("decode", 1)])
+def test_deepseek_share_serve_steps_fit_and_touch_only_new_entries(
+        one_chip, phase, chunk):
+    """The rag-decode cell's shapes: the DeepSeek-V2-Lite chip share (8 of
+    64 routed experts) at published widths, batch 16, max_len 4352,
+    4096-token prompts, the latent cache donated.  Each step fits the chip;
+    both read the stacked latent cache where it lies and write only the
+    chunk's entries (no copy or transpose of the [27, B, max_len, 512] or
+    [27, B, max_len, 64] cache or of a layer's slice of it, the prefix
+    layer's included); decode needs under 64 MiB of scratch."""
+    cfg = get_config("deepseek-v2-lite")
+    b = 16
+    compiled, cache = _compile_serve_step(cfg, one_chip, phase, b, chunk, 4352)
+    touched, new_entries = _cache_touches(
+        compiled, [x.shape for x in cache["mla"].values()], b, chunk,
+        [cfg.moe.n_dense_prefix, cfg.n_layers - cfg.moe.n_dense_prefix])
+    assert touched, "the cache update was not found in the compiled program"
+    assert all(op == "dynamic-update-slice" and update in new_entries
+               for _, op, update in touched), touched
+    if phase == "decode":
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
     assert _device_bytes(compiled) < HBM_BYTES
 
 
