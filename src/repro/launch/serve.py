@@ -22,6 +22,7 @@ import numpy as np
 from .. import obs
 from ..configs import get_config
 from ..models import init_cache, init_model
+from ..models.layers import prefill_score_share
 from ..runtime.steps import prefill_step, serve_step
 from .compile_cache import use_compile_cache
 from .trace_dir import traced
@@ -68,7 +69,8 @@ class Server:
                 emb = self._embed_stub(prompts)
                 if emb is not None:
                     batch["embeds"] = jnp.asarray(emb, jnp.bfloat16)
-            with obs.span("serve.prefill"):
+            share = prefill_score_share(self.cfg, s0)
+            with obs.span("serve.prefill", attn_score_share=share):
                 t0 = time.perf_counter()
                 logits, cache = self._prefill(self.params, cache, batch)
                 logits.block_until_ready()

@@ -10,7 +10,7 @@ Conventions:
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -147,15 +147,49 @@ def init_attention(cfg: ModelConfig, key) -> Tuple[Params, PyTree]:
     return p, a
 
 
+class KeyGroups(NamedTuple):
+    """How `blocked_causal_attention` splits its queries: chunks of `chunk`
+    queries, run in consecutive `groups` of (first chunk, chunks, key
+    extent), which score `share` of the chunks' whole score square."""
+    chunk: int
+    groups: Tuple[Tuple[int, int, int], ...]
+    share: float
+
+
+MAX_KEY_GROUPS = 8   # each group adds one attention body to the program
+
+
+def causal_key_groups(sq: int, q_chunk: int) -> KeyGroups:
+    """Split `sq` causal queries into the fewest chunks of at most `q_chunk`,
+    all of one length, and the chunks into at most `MAX_KEY_GROUPS`
+    consecutive groups whose sizes differ by at most one.  A group's keys
+    end at its last real query, so the key blocks wholly in its chunks'
+    future are never scored: G equal groups score (G+1)/(2G) of the square,
+    one chunk all of it."""
+    nchunks = -(-sq // q_chunk)
+    chunk = -(-sq // nchunks)
+    ngroups = min(nchunks, MAX_KEY_GROUPS)
+    groups, first = [], 0
+    for g in range(ngroups):
+        n = nchunks // ngroups + (g < nchunks % ngroups)
+        groups.append((first, n, min(sq, (first + n) * chunk)))
+        first += n
+    share = sum(n * extent for _, n, extent in groups) / (nchunks * sq)
+    return KeyGroups(chunk, tuple(groups), share)
+
+
 def blocked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                              scale: float, *, cache=None, cache_len=None,
                              q_chunk: int = 512) -> jnp.ndarray:
     """Memory-bounded causal GQA attention.
 
     q [B,Sq,H,dh]; k,v [B,Sq,Hkv,dh], the keys and values of the same
-    positions as q.  Streams over query chunks with `lax.map` so peak memory
-    is O(q_chunk * T) per head instead of O(Sq * T): mandatory at 4k-32k
-    sequence lengths on 16GB HBM.
+    positions as q.  Streams over query chunks in a loop so peak memory is
+    O(q_chunk * T) per head instead of O(Sq * T): mandatory at 4k-32k
+    sequence lengths on 16GB HBM.  Over several chunks, the chunks run in
+    groups (`causal_key_groups`), one loop after another, and each chunk
+    scores only the keys up to its group's last query: the entries left
+    out are those the causal mask would zero.
 
     With `cache` = (ck, cv) [B,T,Hkv,dh], q/k/v are a new chunk that follows
     the cache's first `cache_len` entries: every query also attends to those,
@@ -168,8 +202,8 @@ def blocked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     b, sq, h, dh = q.shape
     hkv = k.shape[2]
     rep = h // hkv
-    nchunks = -(-sq // q_chunk)
-    q_chunk = -(-sq // nchunks)
+    q_chunk, groups, _ = causal_key_groups(sq, q_chunk)
+    nchunks = sum(n for _, n, _ in groups)
     pad = nchunks * q_chunk - sq
     if pad:
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -182,28 +216,44 @@ def blocked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         t = ckf.shape[1]
         cache_mask = jnp.arange(t) < cache_len                  # [T]
 
-    def one_chunk(ci):
-        qc = qg[:, ci]                                          # [B,qc,G,R,dh]
-        sc = jnp.einsum("bsgrd,btgd->bgrst", qc, kf) * scale    # [B,G,R,qc,Sq]
+    def one_chunk(qc, ci, extent):                              # qc [B,qc,G,R,dh]
+        kc, vc, k_idx = ((kf, vf, s_idx) if extent == sq else
+                         (kf[:, :extent], vf[:, :extent], s_idx[:extent]))
+        sc = jnp.einsum("bsgrd,btgd->bgrst", qc, kc) * scale    # [B,G,R,qc,E]
         q_idx = ci * q_chunk + jnp.arange(q_chunk)
-        mask = s_idx[None, :] <= q_idx[:, None]                 # [qc, Sq]
+        mask = k_idx[None, :] <= q_idx[:, None]                 # [qc, E]
         sc = jnp.where(mask[None, None, None], sc, -1e30)
         if cache is None:
             w = jax.nn.softmax(sc, axis=-1)
-            return jnp.einsum("bgrst,btgd->bsgrd", w, vf)       # [B,qc,G,R,dh]
+            return jnp.einsum("bgrst,btgd->bsgrd", w, vc)       # [B,qc,G,R,dh]
         scc = jnp.einsum("bsgrd,btgd->bgrst", qc, ckf) * scale  # [B,G,R,qc,T]
         scc = jnp.where(cache_mask, scc, -1e30)
         w = jax.nn.softmax(jnp.concatenate([scc, sc], axis=-1), axis=-1)
         return (jnp.einsum("bgrst,btgd->bsgrd", w[..., :t], cvf)
-                + jnp.einsum("bgrst,btgd->bsgrd", w[..., t:], vf))
+                + jnp.einsum("bgrst,btgd->bsgrd", w[..., t:], vc))
 
-    out = jax.lax.map(one_chunk, jnp.arange(nchunks))           # [NC,B,qc,G,R,dv]
     dv = v.shape[-1]  # may differ from q/k head dim (MLA)
-    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq + pad, h, dv)[:, :sq]
+    if len(groups) == 1:
+        out = jax.lax.map(lambda ci: one_chunk(qg[:, ci], ci, sq),
+                          jnp.arange(nchunks))          # [NC,B,qc,G,R,dv]
+        out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq + pad, h, dv)
+    else:
+        # one group after another, each chunk writing its rows into one
+        # output buffer: no group's rows are held beside it
+        out = jnp.zeros((b, sq + pad, h, dv), q.dtype)
+        for first, n, extent in groups:
+            qs = qg[:, first:first + n]                 # the group's chunks
+
+            def write(i, o):
+                rows = one_chunk(qs[:, i], first + i, extent)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    o, rows.reshape(b, q_chunk, h, dv).astype(q.dtype),
+                    (first + i) * q_chunk, axis=1)
+            out = jax.lax.fori_loop(0, n, write, out)
     # cast back to the storage dtype at the boundary: keeps the fwd output
     # AND its backward cotangent chain (the TP partial-sum all-reduces) in
     # bf16 instead of f32 — halves the dominant collective (§Perf iter-3)
-    return out.astype(q.dtype)
+    return out[:, :sq].astype(q.dtype)
 
 
 def attention_fwd(p: Params, x: jnp.ndarray, cfg: ModelConfig,
@@ -324,6 +374,20 @@ def mla_softmax_scale(cfg: ModelConfig) -> float:
     return scale
 
 
+MLA_PREFILL_Q_CHUNK = 256
+
+
+def prefill_score_share(cfg: ModelConfig, s: int) -> float:
+    """The share of the causal score square that each attention layer of a
+    prefill of `s` tokens scores (`causal_key_groups` at the prefill's query
+    chunk: `mla_fwd`'s, else `attention_fwd`'s default 512); 1.0 for a model
+    without attention."""
+    if cfg.family == "ssm":
+        return 1.0
+    q_chunk = MLA_PREFILL_Q_CHUNK if cfg.mla is not None else 512
+    return causal_key_groups(s, q_chunk).share
+
+
 def mla_fwd(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions,
             *, kv_cache: Optional[Dict[str, jnp.ndarray]] = None,
             cache_pos=None):
@@ -372,7 +436,7 @@ def mla_fwd(p: Params, x: jnp.ndarray, cfg: ModelConfig, positions,
         with jax.named_scope("mla.attend"):
             out = constrain_heads(blocked_causal_attention(
                 q_cat, k_cat, constrain_heads(v), scale,
-                q_chunk=512 if kv_cache is None else 256))
+                q_chunk=512 if kv_cache is None else MLA_PREFILL_Q_CHUNK))
     else:
         f32 = jnp.float32
         cc, cr = kv_cache["ckv"], kv_cache["krope"]         # [B,T,r], [B,T,dr]

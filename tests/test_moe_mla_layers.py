@@ -1,7 +1,8 @@
 """DeepSeek-V2's layer mechanics at small sizes on the CPU: YaRN's rotary
 frequencies and softmax scale against hand-computed values, the MoE layer
 dropping nothing however skewed the routing, blocked attention and the MoE
-token chunks at any length, and the MoE layer under an expert-split mesh."""
+token chunks at any length, blocked attention scoring only the keys a query
+chunk's group can see, and the MoE layer under an expert-split mesh."""
 import math
 from dataclasses import replace
 
@@ -113,6 +114,9 @@ def _naive_attention(q, k, v, scale, cache=None, cache_len=0):
     (601, 512, False),     # two chunks of 301, one query of padding
     (4099, 256, False),    # a prime: 17 chunks of 242, 15 of padding
     (301, 256, True),      # after 33 of a cache's 40 entries
+    (4096, 256, False),    # 16 chunks in 8 groups of two
+    (2250, 256, False),    # 9 chunks of 250: one group of two, seven of one
+    (1000, 256, True),     # 4 chunks of 250 after the cache's 33 entries
 ])
 def test_blocked_attention_takes_any_length(sq, q_chunk, cached):
     ks = jax.random.split(jax.random.PRNGKey(sq), 5)
@@ -127,6 +131,97 @@ def test_blocked_attention_takes_any_length(sq, q_chunk, cached):
     assert got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
+
+
+def test_blocked_attention_gradient_matches_naive():
+    """The training path: 300 queries in 5 chunks of 60, each group scoring
+    only its keys.  The gradients with respect to q, k and v are the naive
+    attention's."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (2, 300, 4, 8))
+    k = jax.random.normal(ks[1], (2, 300, 2, 8))
+    v = jax.random.normal(ks[2], (2, 300, 2, 8))
+    ct = jax.random.normal(ks[3], (2, 300, 4, 8))
+
+    def grads(attend):
+        return jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) * ct),
+                        argnums=(0, 1, 2))(q, k, v)
+    got = grads(lambda q, k, v: L.blocked_causal_attention(q, k, v, 0.35,
+                                                           q_chunk=64))
+    want = grads(lambda q, k, v: _naive_attention(q, k, v, 0.35))
+    assert len(L.causal_key_groups(300, 64).groups) == 5
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("sq,q_chunk", [
+    (300, 512), (512, 512), (300, 256), (601, 512), (4096, 256),
+    (4099, 256), (2250, 256), (4096, 512), (32768, 512), (7, 1)])
+def test_key_groups_cover_every_chunk_once_within_sq(sq, q_chunk):
+    chunk, groups, share = L.causal_key_groups(sq, q_chunk)
+    nchunks = -(-sq // chunk)
+    assert chunk <= q_chunk and nchunks * chunk - sq < chunk
+    assert [first for first, _, _ in groups] == list(
+        np.cumsum([0] + [n for _, n, _ in groups[:-1]]))
+    assert sum(n for _, n, _ in groups) == nchunks
+    assert len(groups) == min(nchunks, L.MAX_KEY_GROUPS)
+    assert max(n for _, n, _ in groups) - min(n for _, n, _ in groups) <= 1
+    for first, n, extent in groups:
+        # the keys end at the group's last real query, never past sq
+        assert extent == min(sq, (first + n) * chunk)
+    assert groups[-1][2] == sq
+    want = sum(n * chunk * e for _, n, e in groups) / (nchunks * chunk * sq)
+    assert share == pytest.approx(want)
+
+
+def test_key_groups_share_of_the_square():
+    """16 chunks score (8+1)/16 of the square; one chunk scores all of it,
+    in one group whose keys are all sq."""
+    assert L.causal_key_groups(4096, 256).share == 0.5625 <= 0.57
+    assert L.causal_key_groups(300, 512) == (300, ((0, 1, 300),), 1.0)
+    assert L.prefill_score_share(get_config("deepseek-v2-lite"), 4096) == 0.5625
+    assert L.prefill_score_share(get_config("stablelm-3b"), 512) == 1.0
+    assert L.prefill_score_share(get_config("mamba2-130m"), 4096) == 1.0
+
+
+def _dot_shapes(jaxpr):
+    """The operand shapes of every dot_general in `jaxpr`, nested too."""
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "dot_general":
+            out.append([x.aval.shape for x in e.invars])
+        for p in e.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _dot_shapes(sub)
+    return out
+
+
+@pytest.mark.parametrize("sq,q_chunk", [(4096, 256), (300, 512)])
+def test_blocked_attention_scores_only_its_groups_keys(sq, q_chunk):
+    """In the traced program each group is one loop over its chunks, and
+    every product in it (scores and the weighted value sum) spans the
+    group's key extent: at 16 chunks 512, 1024, ... and all sq only in the
+    last group; at one chunk one loop over all sq keys."""
+    groups = L.causal_key_groups(sq, q_chunk).groups
+    q = jax.ShapeDtypeStruct((1, sq, 4, 8), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, sq, 2, 8), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: L.blocked_causal_attention(
+        q, k, v, 0.35, q_chunk=q_chunk))(q, kv, kv).jaxpr
+    loops = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(loops) == len(groups)
+    for (first, n, extent), loop in zip(groups, loops):
+        assert loop.params["length"] == n
+        dots = _dot_shapes(loop.params["jaxpr"].jaxpr)
+        assert len(dots) == 2
+        assert [s[1] for shapes in dots for s in shapes if len(s) == 4] == [
+            extent, extent]
+    extents = [e for _, _, e in groups]
+    assert extents[-1] == sq and sq not in extents[:-1]
+    if sq == 4096:
+        assert extents == [512 * (i + 1) for i in range(8)]
 
 
 @pytest.mark.parametrize("s0", [300, 301])

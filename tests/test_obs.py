@@ -248,6 +248,21 @@ def test_server_generate_one_decode_step_per_token(tracer):
         assert [r.parent for r in names["serve.init_cache"]].count(g.id) == 1
 
 
+@pytest.mark.parametrize("arch,s0,share", [
+    ("stablelm-3b", 8, 1.0),             # one query chunk: nothing skipped
+    ("deepseek-v2-lite", 300, 0.75),     # two chunks of 150: 450 of 600 keys
+])
+def test_server_prefill_span_carries_attn_score_share(tracer, arch, s0, share):
+    from repro.launch.serve import Server
+    srv = Server(arch, reduced=True, max_len=s0 + 4)
+    prompts = np.random.default_rng(2).integers(
+        1, srv.cfg.vocab_size, size=(1, s0)).astype(np.int32)
+    srv.generate(prompts, 1)
+    records, _ = obs.drain()
+    [prefill] = [r for r in records if r.name == "serve.prefill"]
+    assert prefill.attrs == {"attn_score_share": share}
+
+
 def test_server_reads_each_token_after_dispatching_its_step(tracer):
     """Within every decode step the host dispatches the next step before it
     waits for the token, and the tokens are the greedy ones that a loop

@@ -25,6 +25,9 @@ from repro.runtime.steps import (abstract_batch, abstract_state,
                                  make_train_step_fn, prefill_step, serve_step)
 
 HBM_BYTES = 16 * 2**30  # one v5e chip
+# the rag-decode prefill (4,096 tokens, batch 16) when each 256-query chunk
+# scored all 4,096 keys: skipping the future key blocks must not cost memory
+DEEPSEEK_PREFILL_BYTES = 14_555_475_456
 
 
 @pytest.fixture(scope="module")
@@ -180,7 +183,8 @@ def test_deepseek_share_serve_steps_fit_and_touch_only_new_entries(
     both read the stacked latent cache where it lies and write only the
     chunk's entries (no copy or transpose of the [27, B, max_len, 512] or
     [27, B, max_len, 64] cache or of a layer's slice of it, the prefix
-    layer's included); decode needs under 64 MiB of scratch."""
+    layer's included); decode needs under 64 MiB of scratch, and prefill
+    no more than it took when every query chunk scored all 4,096 keys."""
     cfg = get_config("deepseek-v2-lite")
     b = 16
     compiled, cache = _compile_serve_step(cfg, one_chip, phase, b, chunk, 4352)
@@ -192,6 +196,8 @@ def test_deepseek_share_serve_steps_fit_and_touch_only_new_entries(
                for _, op, update in touched), touched
     if phase == "decode":
         assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    else:
+        assert _device_bytes(compiled) <= DEEPSEEK_PREFILL_BYTES
     assert _device_bytes(compiled) < HBM_BYTES
 
 
