@@ -64,33 +64,6 @@ def analyze_cell(key: str, rec: Dict) -> Optional[Dict]:
     model_compute_s = (mf["total"] / n_dev) / PEAK_FLOPS
     frac = model_compute_s / max(bound_s, 1e-30)
 
-    # --- Pallas-kernel deployment estimate -------------------------------
-    # On TPU the flash-attention / SSD kernels keep scores (or the SSD
-    # decay quadratic) in VMEM: the attention-interior HBM traffic becomes
-    # just q/k/v/out in bf16.  The XLA path we lower on CPU materializes
-    # them.  Model the deployed memory term by replacing the attention's
-    # measured share with the analytic kernel traffic.
-    la = 0
-    try:
-        from repro.analysis.model_math import n_attn_layers
-        la = n_attn_layers(cfg)
-    except Exception:
-        pass
-    kern_mem_s = None
-    if shape.kind in ("train", "prefill") and la:
-        dh = (cfg.mla.qk_nope_dim + cfg.mla.qk_rope_dim) if cfg.mla \
-            else cfg.head_dim
-        toks = shape.seq_len * shape.global_batch
-        passes = 3.0 if shape.kind == "train" else 1.0
-        qkvo = 4.0 * toks * cfg.n_heads * dh * 2 * passes / n_dev
-        # measured attention-interior traffic ~= everything above the
-        # parameter/optimizer floor that scales with S^2; approximate by
-        # capping the memory term at (non-attention bytes + kernel bytes),
-        # where non-attention bytes ~= hbm_bytes - score-traffic estimate
-        score_traffic = (passes * la * shape.global_batch * cfg.n_heads
-                         * shape.seq_len * shape.seq_len * 4 * 2 / n_dev)
-        non_attn = max(h["hbm_bytes"] - score_traffic, 0.0)
-        kern_mem_s = (non_attn + la * qkvo) / HBM_BW
     return {
         "key": key, "arch": arch, "shape": shape_name, "mesh": mesh,
         "devices": n_dev,
@@ -103,7 +76,6 @@ def analyze_cell(key: str, rec: Dict) -> Optional[Dict]:
         "model_flops_total": mf["total"],
         "useful_flops_ratio": useful,
         "roofline_fraction": frac,
-        "kernelized_memory_s": kern_mem_s,
         "hbm_per_dev_gib": (rec["memory"]["argument_bytes"]
                             + rec["memory"]["temp_bytes"]) / 2**30,
     }

@@ -1,18 +1,13 @@
-"""Mamba2 (SSD — state-space duality) layer, pure JAX reference.
+"""Mamba2 (SSD — state-space duality) layer in pure JAX.
 
 Chunked SSD algorithm (arXiv:2405.21060): within-chunk computation is a
 masked quadratic form (MXU-friendly), across chunks a tiny sequential scan
 carries the [H, P, N] state.  Decode is the O(1) recurrence
     h_t = a_t * h_{t-1} + (dt_t x_t) outer B_t ;  y_t = C_t . h_t + D x_t
 which is what makes SSM/hybrid architectures runnable at 500k context.
-
-The Pallas kernel in `repro.kernels.ssd_scan` implements the same chunked
-computation with explicit VMEM tiling; this module is its oracle (ref) and
-the default XLA path.
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -81,9 +76,8 @@ def _causal_conv(xbc: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
 
 
 def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
-                B: jnp.ndarray, C: jnp.ndarray, chunk: int,
-                h0: Optional[jnp.ndarray] = None):
-    """Chunked SSD scan (the oracle the Pallas kernel must match).
+                B: jnp.ndarray, C: jnp.ndarray, chunk: int):
+    """Chunked SSD scan from a zero state.
 
     x  [B,S,H,P]  inputs per head
     dt [B,S,H]    softplus'd timestep
@@ -128,10 +122,8 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
         hnew = hprev * dec[..., None, None] + st
         return hnew, hprev                                     # emit state ENTERING chunk
 
-    h_init = (jnp.zeros((b, h, p, n), jnp.float32) if h0 is None
-              else h0.astype(jnp.float32))
     h_last, h_enter = jax.lax.scan(
-        step, h_init,
+        step, jnp.zeros((b, h, p, n), jnp.float32),
         (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)))
     h_enter = h_enter.transpose(1, 0, 2, 3, 4)                 # [B,NC,H,P,N]
 

@@ -1,5 +1,5 @@
-"""repro.runtime — distribution: sharding rules, step functions, fault
-tolerance, gradient compression, pipeline parallelism."""
+"""repro.runtime — distribution: sharding rules, step functions, gradient
+compression, pipeline parallelism."""
 from . import sharding
 from .steps import (abstract_batch, abstract_cache, abstract_state,
                     make_train_state, make_train_step_fn, model_axes,

@@ -1,8 +1,10 @@
 """DeepSeek-V2's layer mechanics at small sizes on the CPU: YaRN's rotary
 frequencies and softmax scale against hand-computed values, the MoE layer
 dropping nothing however skewed the routing, blocked attention and the MoE
-token chunks at any length, blocked attention scoring only the keys a query
-chunk's group can see, and the MoE layer under an expert-split mesh."""
+token chunks at any length, blocked attention against naive attention in
+f32 and bf16 (prefill, training gradients, one decode step over the cache),
+blocked attention scoring only the keys a query chunk's group can see, and
+the MoE layer under an expert-split mesh."""
 import math
 from dataclasses import replace
 
@@ -117,6 +119,10 @@ def _naive_attention(q, k, v, scale, cache=None, cache_len=0):
     (4096, 256, False),    # 16 chunks in 8 groups of two
     (2250, 256, False),    # 9 chunks of 250: one group of two, seven of one
     (1000, 256, True),     # 4 chunks of 250 after the cache's 33 entries
+    (128, 16, False),      # 8 chunks of 16, in 8 groups of one
+    (128, 32, False),      # 4 chunks of 32, in 4 groups of one
+    (128, 48, False),      # 3 chunks of 43, one query of padding
+    (128, 128, False),     # one chunk
 ])
 def test_blocked_attention_takes_any_length(sq, q_chunk, cached):
     ks = jax.random.split(jax.random.PRNGKey(sq), 5)
@@ -133,26 +139,129 @@ def test_blocked_attention_takes_any_length(sq, q_chunk, cached):
                                rtol=1e-4, atol=1e-5)
 
 
-def test_blocked_attention_gradient_matches_naive():
-    """The training path: 300 queries in 5 chunks of 60, each group scoring
-    only its keys.  The gradients with respect to q, k and v are the naive
-    attention's."""
+@pytest.mark.parametrize("b,sq,d,q_chunk,n_groups", [
+    (2, 300, 8, 64, 5),    # 5 chunks of 60
+    (1, 64, 32, 32, 2),    # 2 chunks of 32
+])
+def test_blocked_attention_gradient_matches_naive(b, sq, d, q_chunk,
+                                                  n_groups):
+    """The training path: 4 query heads over 2 KV heads, the queries in
+    chunks of at most `q_chunk`, each group scoring only its keys.  The
+    gradients with respect to q, k and v are the naive attention's."""
     ks = jax.random.split(jax.random.PRNGKey(11), 4)
-    q = jax.random.normal(ks[0], (2, 300, 4, 8))
-    k = jax.random.normal(ks[1], (2, 300, 2, 8))
-    v = jax.random.normal(ks[2], (2, 300, 2, 8))
-    ct = jax.random.normal(ks[3], (2, 300, 4, 8))
+    q = jax.random.normal(ks[0], (b, sq, 4, d))
+    k = jax.random.normal(ks[1], (b, sq, 2, d))
+    v = jax.random.normal(ks[2], (b, sq, 2, d))
+    ct = jax.random.normal(ks[3], (b, sq, 4, d))
 
     def grads(attend):
         return jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) * ct),
                         argnums=(0, 1, 2))(q, k, v)
     got = grads(lambda q, k, v: L.blocked_causal_attention(q, k, v, 0.35,
-                                                           q_chunk=64))
+                                                           q_chunk=q_chunk))
     want = grads(lambda q, k, v: _naive_attention(q, k, v, 0.35))
-    assert len(L.causal_key_groups(300, 64).groups) == 5
+    assert len(L.causal_key_groups(sq, q_chunk).groups) == n_groups
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-5)
+
+
+# f32 as the naive attention; bf16 to the rounding of the bf16 result
+TOL = dict(rtol=1e-4, atol=1e-5)
+TOL_BF16 = dict(rtol=1e-2, atol=1e-2)
+
+
+def _normal(key, shape, dtype):
+    return jax.random.normal(key, shape, jnp.float32).astype(dtype)
+
+
+@pytest.mark.parametrize("b,h,hkv,s,d", [
+    (1, 2, 2, 64, 32),     # MHA
+    (2, 4, 2, 128, 32),    # GQA, 2 query heads a KV head
+    (1, 8, 1, 128, 64),    # MQA
+    (1, 4, 4, 96, 16),     # 3 chunks of 32
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_blocked_attention_matches_naive(b, h, hkv, s, d, dtype):
+    """Prefill and training attention over chunks of 32 queries, in f32 and
+    in the model's bf16, against the naive attention in f32."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = _normal(ks[0], (b, s, h, d), dtype)
+    k = _normal(ks[1], (b, s, hkv, d), dtype)
+    v = _normal(ks[2], (b, s, hkv, d), dtype)
+    scale = 1.0 / math.sqrt(d)
+    got = L.blocked_causal_attention(q, k, v, scale, q_chunk=32)
+    want = _naive_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                            scale)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               **(TOL if dtype == jnp.float32 else TOL_BF16))
+
+
+def _decode_attention_ref(q, k, v, lengths, scale):
+    """One new query per sequence over a cache that already holds its key:
+    q [B,H,D]; k, v [B,T,Hkv,D]; each sequence b attends to its first
+    lengths[b] entries.  Returns [B,H,D] in f32."""
+    b, h, d = q.shape
+    t, hkv = k.shape[1], k.shape[2]
+    qf = q.astype(jnp.float32).reshape(b, hkv, h // hkv, d)
+    sc = jnp.einsum("bgrd,btgd->bgrt", qf, k.astype(jnp.float32)) * scale
+    valid = jnp.arange(t)[None, :] < lengths[:, None]          # [B, T]
+    sc = jnp.where(valid[:, None, None, :], sc, -jnp.inf)
+    w = jax.nn.softmax(sc, axis=-1)
+    out = jnp.einsum("bgrt,btgd->bgrd", w, v.astype(jnp.float32))
+    return out.reshape(b, h, d)
+
+
+def _decode_case(b, h, hkv, t, d, dtype, seed):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = _normal(ks[0], (b, 1, h, d), dtype)
+    k_new = _normal(ks[1], (b, 1, hkv, d), dtype)
+    v_new = _normal(ks[2], (b, 1, hkv, d), dtype)
+    ck = _normal(ks[3], (b, t, hkv, d), dtype)
+    cv = _normal(ks[4], (b, t, hkv, d), dtype)
+    return q, k_new, v_new, ck, cv
+
+
+@pytest.mark.parametrize("b,h,hkv,t,d,length", [
+    (2, 8, 2, 64, 32, 37),
+    (1, 4, 4, 128, 64, 128),   # the cache's last entry
+    (4, 16, 1, 64, 32, 1),     # MQA, the first token
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_step_attention_matches_ref(b, h, hkv, t, d, length, dtype):
+    """One decode step as `attention_fwd` runs it: the new token's query,
+    key and value beside the cache, whose first length-1 entries are read
+    in place.  The reference sees the cache with the new key written at
+    length-1."""
+    q, k_new, v_new, ck, cv = _decode_case(b, h, hkv, t, d, dtype, 0)
+    scale = 1.0 / math.sqrt(d)
+    got = L.blocked_causal_attention(q, k_new, v_new, scale, cache=(ck, cv),
+                                     cache_len=jnp.int32(length - 1))
+    want = _decode_attention_ref(
+        q[:, 0], ck.at[:, length - 1].set(k_new[:, 0]),
+        cv.at[:, length - 1].set(v_new[:, 0]),
+        jnp.full((b,), length, jnp.int32), scale)
+    assert got.shape == (b, 1, h, d) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got[:, 0], np.float32),
+                               np.asarray(want),
+                               **(TOL if dtype == jnp.float32 else TOL_BF16))
+
+
+def test_decode_step_reads_only_the_cache_entries_below_cache_len():
+    """The in-place decode trusts only the cache's first `cache_len`
+    entries: entries at and past it, set to +-1e4, change nothing."""
+    b, h, hkv, t, d, cache_len = 3, 4, 2, 64, 32, 17
+    q, k_new, v_new, ck, cv = _decode_case(b, h, hkv, t, d, jnp.float32, 5)
+    sign = jnp.array([1.0, -1.0, 1.0])[:, None, None, None]
+    ck2 = ck.at[:, cache_len:].set(1e4 * sign)
+    cv2 = cv.at[:, cache_len:].set(-1e4 * sign)
+
+    def attend(cache):
+        return L.blocked_causal_attention(q, k_new, v_new, 0.2, cache=cache,
+                                          cache_len=jnp.int32(cache_len))
+    np.testing.assert_array_equal(np.asarray(attend((ck2, cv2))),
+                                  np.asarray(attend((ck, cv))))
 
 
 @pytest.mark.parametrize("sq,q_chunk", [

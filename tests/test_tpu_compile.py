@@ -1,8 +1,8 @@
 """Compile the main-path programs for a TPU v5e chip that is described, not
 attached: the trainer's step and the server's prefill and decode at full
 width (stablelm-3b, and the DeepSeek-V2-Lite chip share at its cell's
-shapes), and the Pallas RMSNorm kernel at the model widths.  Nothing runs;
-the TPU compiler refuses what the chip could not run (tiling, VMEM, HBM).
+shapes).  Nothing runs; the TPU compiler refuses what the chip could not
+run (tiling, VMEM, HBM).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every pytest
@@ -18,7 +18,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.configs.base import InputShape
-from repro.kernels.rmsnorm.ops import rmsnorm_op
 from repro.models import init_cache
 from repro.optim import AdamWConfig
 from repro.runtime.steps import (abstract_batch, abstract_state,
@@ -199,11 +198,3 @@ def test_deepseek_share_serve_steps_fit_and_touch_only_new_entries(
     else:
         assert _device_bytes(compiled) <= DEEPSEEK_PREFILL_BYTES
     assert _device_bytes(compiled) < HBM_BYTES
-
-
-@pytest.mark.parametrize("rows,d", [(4 * 128, 2560), (8 * 2048, 768)])
-def test_rmsnorm_kernel_compiles(one_chip, rows, d):
-    x = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip)
-    scale = jax.ShapeDtypeStruct((d,), jnp.bfloat16, sharding=one_chip)
-    compiled = rmsnorm_op.lower(x, scale).compile()
-    assert "tpu_custom_call" in compiled.as_text()
